@@ -39,6 +39,9 @@ for _p in _paths:
 
 def main() -> None:
     t0 = time.time()
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (joins, groupby_bench, groupjoin_bench,
                             integration_bench, engine_bench)
     from benchmarks.common import ROWS

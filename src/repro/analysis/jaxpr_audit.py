@@ -30,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 import numpy as np
 
 SORT_PRIMS = frozenset({"sort"})

@@ -24,9 +24,10 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+from collections.abc import Collection
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 import numpy as np
 
 from .contracts import FloatScatterViolation, GridAliasViolation, VmemBudgetViolation
@@ -60,11 +61,8 @@ class KernelLintReport:
 
 
 def _block_bytes(bm) -> int:
-    dtype = np.dtype(bm.array_shape_dtype.dtype)
-    size = 1
-    for d in bm.block_shape:
-        size *= int(d) if isinstance(d, (int, np.integer)) else 1
-    return size * dtype.itemsize
+    aval = bm.block_aval  # the kernel's view of one block (squeezed dims dropped)
+    return int(np.prod(aval.shape, dtype=np.int64)) * np.dtype(aval.dtype).itemsize
 
 
 def _is_output(bm, index: int, num_inputs: int) -> bool:
@@ -89,8 +87,7 @@ def _depends_on(jaxpr, tainted_vars) -> bool:
 
 def _eval_index_map(closed, grid_point, extra_avals):
     dummies = [np.zeros(a.shape, a.dtype) for a in extra_avals]
-    outs = jcore.eval_jaxpr(closed.jaxpr, closed.consts,
-                            *map(np.int32, grid_point), *dummies)
+    outs = jcore.jaxpr_as_fun(closed)(*map(np.int32, grid_point), *dummies)
     return tuple(int(np.asarray(o)) for o in outs)
 
 
@@ -162,11 +159,20 @@ def lint_pallas_eqn(eqn, *, name: str, backend: str = DEFAULT_BACKEND,
         kernel_scatter_adds=scatter_adds, violations=violations)
 
 
+def _kernel_name(eqn) -> str:
+    """The kernel body's function name (`_hist_kernel`), or ''."""
+    info = getattr(eqn.params["jaxpr"], "debug_info", None)
+    return str(getattr(info, "func_src_info", "") or "").split(" ")[0]
+
+
 def lint_fn(fn, *args, name: str | None = None,
             backend: str = DEFAULT_BACKEND,
-            allow_output_revisit: bool = False,
+            allow_output_revisit: bool | Collection[str] = False,
             **kwargs) -> list[KernelLintReport]:
-    """Trace `fn(*args, **kwargs)` and lint every pallas_call inside."""
+    """Trace `fn(*args, **kwargs)` and lint every pallas_call inside.
+    `allow_output_revisit` is True for every kernel, or the names of the
+    kernel bodies that revisit output blocks by design; the others stay
+    checked."""
     # close over the args: static ints (num_bins, tile sizes) must reach
     # the kernel wrapper as Python values, not tracers
     closed = jax.make_jaxpr(lambda: fn(*args, **kwargs))()
@@ -175,10 +181,12 @@ def lint_fn(fn, *args, name: str | None = None,
     for i, eqn in enumerate(walk_eqns(closed.jaxpr)):
         if eqn.primitive.name != "pallas_call":
             continue
-        kname = str(eqn.params.get("name_and_src_info", "")).split(" ")[0]
+        kname = _kernel_name(eqn)
+        allow = (allow_output_revisit if isinstance(allow_output_revisit, bool)
+                 else kname in allow_output_revisit)
         reports.append(lint_pallas_eqn(
             eqn, name=f"{label}/{kname or i}", backend=backend,
-            allow_output_revisit=allow_output_revisit))
+            allow_output_revisit=allow))
     return reports
 
 
@@ -193,11 +201,14 @@ def enforce(reports: list[KernelLintReport]) -> None:
 # shapes, with intentional hazards declared
 # ---------------------------------------------------------------------------
 def production_kernel_specs():
-    """(name, thunk, allow_output_revisit) for every production kernel.
+    """(name, thunk, revisiting kernels) for every production kernel.
     Thunks build (fn, args, kwargs) at call time so jax only initializes
-    when the sweep runs. histogram declares output revisiting: its single
-    output block is accumulated across the (sequential) TPU grid by
-    design."""
+    when the sweep runs. The histogram kernels declare output revisiting:
+    `_hist_kernel` accumulates its single output block across the
+    (sequential) TPU grid, and `_block_hist_kernel` (under block_histograms
+    and partition_ranks) shares one (bins, 128) output block between 128
+    consecutive grid steps, each writing its own lane column. Every other
+    kernel, partition_ranks' `_rank_kernel` among them, is checked."""
     import jax.numpy as jnp
 
     from repro.kernels.gather import gather_windowed_pallas
@@ -212,9 +223,9 @@ def production_kernel_specs():
         return jnp.asarray(x, jnp.int32)
 
     def digits():
-        # 4096 rows -> a 4-step grid, so histogram's intentional output
-        # revisiting (sequential accumulation) is actually exercised
-        return i32(np.arange(4096) % 16)
+        # 4 blocks of 64x128 digits -> a 4-step grid, so the intentional
+        # output revisiting (sequential accumulation) is actually exercised
+        return i32(np.arange(4 * 64 * 128) % 16)
 
     def probe_layout():
         bkeys = i32(np.arange(4 * 128).reshape(4, 128))
@@ -231,28 +242,31 @@ def production_kernel_specs():
         return (bkeys, bvals, pk, gkb, pvb, part)
 
     return [
-        ("histogram", lambda: (histogram_pallas, (digits(), 16), {}), True),
+        ("histogram", lambda: (histogram_pallas, (digits(), 16), {}),
+         ("_hist_kernel",)),
         ("block_histograms",
-         lambda: (block_histograms_pallas, (digits(), 16), {}), False),
+         lambda: (block_histograms_pallas, (digits(), 16), {}),
+         ("_block_hist_kernel",)),
         ("partition_ranks",
-         lambda: (partition_ranks_pallas, (digits(), 16), {}), False),
+         lambda: (partition_ranks_pallas, (digits(), 16), {}),
+         ("_block_hist_kernel",)),
         ("segsum_partials",
          lambda: (segsum_partials_pallas,
                   (i32(np.sort(np.arange(1024) % 64)),
-                   jnp.ones((1024,), jnp.float32)), {}), False),
+                   jnp.ones((1024,), jnp.float32)), {}), ()),
         ("gather_windowed",
          lambda: (gather_windowed_pallas,
                   (jnp.ones((4096,), jnp.float32), i32(np.arange(2048)),
-                   i32([0, 1])), {}), False),
+                   i32([0, 1])), {}), ()),
         ("lower_bound_windowed",
          lambda: (lower_bound_windowed_pallas,
                   (i32(np.arange(2048)), i32(np.arange(2048)),
-                   i32([0, 1])), {}), False),
+                   i32([0, 1])), {}), ()),
         ("hash_probe",
-         lambda: (hash_probe_pallas, probe_layout(), {}), False),
+         lambda: (hash_probe_pallas, probe_layout(), {}), ()),
         ("probe_agg",
          lambda: (probe_agg_pallas, probe_agg_args(),
-                  {"col_sides": (("build", 0), ("probe", 0))}), False),
+                  {"col_sides": (("build", 0), ("probe", 0))}), ()),
     ]
 
 

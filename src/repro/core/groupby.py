@@ -164,8 +164,9 @@ def _tile_partials(keys, cols_ops, block):
     for name, (vals, pop) in cols_ops.items():
         vp = jnp.pad(vals, (0, n_pad)).reshape(-1, block)
         vs = jnp.take_along_axis(vp, order, axis=1).astype(jnp.float32)
-        if pop == "sum":
-            acc = jnp.einsum("tb,tbg->tg", jnp.where(valid, vs, 0.0), oh)
+        if pop == "sum":  # HIGHEST: a TPU's default f32 matmul rounds to bf16
+            acc = jnp.einsum("tb,tbg->tg", jnp.where(valid, vs, 0.0), oh,
+                             precision=jax.lax.Precision.HIGHEST)
         elif pop == "count":
             acc = pcounts
         elif pop in ("min", "max"):

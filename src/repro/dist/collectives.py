@@ -16,7 +16,6 @@ from __future__ import annotations
 from typing import Any, NamedTuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
@@ -86,8 +85,8 @@ def compressed_psum_dp(grads, ef: EFState, mesh, *, axis="data"):
         return summed, new_ef.error
 
     rep = jax.tree_util.tree_map(lambda _: PartitionSpec(), grads)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(rep, rep), out_specs=(rep, rep),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(rep, rep), out_specs=(rep, rep),
+                       check_vma=False)
     out, new_err = fn(grads, ef.error)
     return out, EFState(error=new_err)

@@ -13,7 +13,6 @@ transformer blocks.
 from __future__ import annotations
 
 import jax
-from jax.experimental.shard_map import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
@@ -85,9 +84,9 @@ def pipeline_forward(stage_fn, stages, x, *, mesh=None, axis=None,
         return jax.lax.psum(jnp.where(stage == n_stages - 1, out, 0.0), axis)
 
     spec_stage = jax.tree_util.tree_map(lambda _: PartitionSpec(axis), stages)
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(spec_stage, PartitionSpec()),
-                   out_specs=PartitionSpec(),
-                   check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(spec_stage, PartitionSpec()),
+                       out_specs=PartitionSpec(),
+                       check_vma=False)
     y = fn(stages, xm)
     return y.reshape((B,) + y.shape[2:])

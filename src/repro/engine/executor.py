@@ -36,9 +36,24 @@ from . import membudget
 from . import physical as P
 from .logical import FILTER_OP_FNS
 
-# Programming errors must surface, not trigger a degraded re-plan: a retried
-# plan would either hit the same bug or silently mask it (DESIGN.md §13).
-_NON_DEGRADABLE = (TypeError, KeyError, AttributeError, IndexError)
+
+class CapacitySaturated(RuntimeError):
+    """A result's valid rows reached (or overflowed) its static capacity:
+    the result is *suspected* truncated (capacity clamping makes real
+    truncation indistinguishable from an exact fit), so the run is treated
+    as failed and retried with more headroom."""
+
+
+def degradable(e: BaseException) -> bool:
+    """True for the failures a degraded re-plan can fix: an exhausted
+    escalation ladder, a saturated capacity, an injected fault, or an
+    allocation failure. Everything else — a programming error, or a kernel
+    that fails to lower or compile — fails the same way on every plan and
+    must surface instead of being masked by a slower plan (DESIGN.md §13)."""
+    return (isinstance(e, (escalation.EscalationExhausted, CapacitySaturated,
+                           faults.FaultInjected))
+            or membudget.is_memory_error(e))
+
 
 # Checked mode: capacity-sensitive operators run through their resilience
 # ladders (phj_join_checked / groupby_partition_checked / groupjoin_checked)
@@ -346,12 +361,13 @@ def run(plan: "P.PhysicalPlan", tables: Mapping[str, Table] | None = None,
     exact pre-trace code path (no Span allocation, identical whole-plan
     jaxpr — pinned by tests/test_obs.py).
 
-    Graceful degradation (DESIGN.md §13): if the plan raises at trace or
-    run time — an `EscalationExhausted` ladder, a kernel arm that failed
-    past its xla fallback, a fault-injected `raise:executor.run` — the
-    executor re-plans ONCE via `physical.degrade_plan` (doubled
-    capacities, sort/smj strategies) and reruns. Programming errors
-    (`_NON_DEGRADABLE`) and failures of an already-degraded plan re-raise
+    Graceful degradation (DESIGN.md §13): if the plan raises a `degradable`
+    failure at trace or run time — an `EscalationExhausted` ladder, a
+    saturated capacity, an allocation failure, a fault-injected
+    `raise:executor.run` — the executor re-plans ONCE via
+    `physical.degrade_plan` (doubled capacities, sort/smj strategies) and
+    reruns. Every other error (programming errors, kernels that fail to
+    lower or compile) and failures of an already-degraded plan re-raise
     untouched."""
     if trace:
         if counts is not None:
@@ -392,10 +408,8 @@ def run(plan: "P.PhysicalPlan", tables: Mapping[str, Table] | None = None,
 
     try:
         return attempt(plan)
-    except _NON_DEGRADABLE:
-        raise
-    except Exception as e:  # noqa: BLE001 — everything else degrades once
-        if plan.degraded:
+    except Exception as e:  # noqa: BLE001 — degradable failures degrade once
+        if plan.degraded or not degradable(e):
             raise
         reason = f"{type(e).__name__}: {e}"[:120]
         if plan.degraded_plan is None:
@@ -469,7 +483,7 @@ def _recombine(root: P.PhysNode, parts: list):
     # and fits the root capacity whenever the whole plan would have
     total = sum(c for _, c in sliced)
     if total > root.capacity:
-        raise ValueError(
+        raise CapacitySaturated(
             f"morsel recombine overflow: {total} rows exceed the root "
             f"capacity {root.capacity}")
     cat = concat_tables([t for t, _ in sliced])

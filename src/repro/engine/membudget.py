@@ -27,10 +27,9 @@ import os
 
 ENV_VAR = "REPRO_MEM_BUDGET_BYTES"
 
-# Hosts whose backend reports no byte limit (CPU jax returns no
-# memory_stats) get an effectively-unbounded budget: the governor must not
-# change behavior where memory was never the constraint. Tests and the
-# chaos harness force small budgets explicitly.
+# CPU backends report no memory_stats: their budget is effectively
+# unbounded, so the governor changes nothing where memory was never the
+# constraint. Tests and the chaos harness force small budgets explicitly.
 FALLBACK_BUDGET_BYTES = 64 << 30
 
 # Substrings that mark a backend runtime error as an allocation failure.
@@ -71,8 +70,10 @@ def detect_budget_bytes() -> int:
 
     ``REPRO_MEM_BUDGET_BYTES`` (a positive integer, parsed and validated
     per call — the read-time convention) wins when set; otherwise the
-    first local device's reported ``bytes_limit`` (TPU/GPU backends);
-    otherwise FALLBACK_BUDGET_BYTES (CPU backends report no limit)."""
+    first local device's reported ``bytes_limit``; otherwise, on a CPU
+    backend (which reports no limit), FALLBACK_BUDGET_BYTES. An
+    accelerator that reports no limit raises: guessing its size would
+    admit plans the device cannot hold."""
     env = os.environ.get(ENV_VAR)
     if env is not None:
         try:
@@ -84,14 +85,16 @@ def detect_budget_bytes() -> int:
                 f"{ENV_VAR}={env!r} is not a recognized value; allowed: a "
                 "positive integer byte count (e.g. 1073741824 for 1 GiB)")
         return val
-    try:
-        import jax
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit", 0) > 0:
-            return int(stats["bytes_limit"])
-    except Exception:  # noqa: BLE001 — no backend / no stats: fall back
-        pass
+    device = jax.local_devices()[0]
+    stats = device.memory_stats()
+    if stats and stats.get("bytes_limit", 0) > 0:
+        return int(stats["bytes_limit"])
+    if device.platform != "cpu":
+        raise RuntimeError(
+            f"{device.platform} device {device.device_kind!r} reports no "
+            f"bytes_limit; set {ENV_VAR} to its per-device byte budget")
     return FALLBACK_BUDGET_BYTES
 
 

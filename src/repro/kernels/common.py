@@ -1,23 +1,31 @@
 """Shared helpers for the Pallas TPU kernels.
 
 Conventions (see DESIGN.md §2):
-  * 1-D relational arrays are padded and viewed as (rows, 128) so blocks are
-    lane-aligned; row-block sizes are multiples of 8 (f32 sublane).
-  * Integer payloads that flow through one-hot matmuls are split into 16-bit
-    halves so the f32 MXU accumulates them exactly (values < 2^16 are exact
-    in f32; the one-hot has a single 1 per row, so no rounding ever occurs).
+  * Every block's last two dims obey the TPU (8, 128) tiling rule. 1-D
+    relational arrays are padded and viewed as (rows, 128) lane tiles with
+    row-block sizes a multiple of 8; per-grid-step vectors (a probe
+    sub-block, a gather window) travel as (steps, 1, width) arrays whose
+    (1, width) trailing dims equal the array's, so the kernel sees one
+    (1, width) row.
+  * Kernels stay 2-D end to end: a row is never flattened to 1-D, and a
+    row that must meet another row elementwise is transposed into a
+    (width, 1) column, so pairwise work is one (width_a, width_b) tile.
+  * Selections ("the value at the matching slot") are masked sums over an
+    axis, exact for any dtype; float reductions that go through the MXU ask
+    for `Precision.HIGHEST` so the chip keeps full f32.
   * Padding rows in digit arrays always carry PAD_DIGIT (< 0) and are
-    excluded from histograms/ranks by construction (`digit_onehot` masks
-    them), never by relying on a fill value happening to miss a bin.
-  * Kernels default to interpret mode off-TPU (`default_interpret`;
-    override with REPRO_PALLAS_INTERPRET=0/1) and are written with TPU
-    BlockSpecs for the v5e target.
+    excluded from histograms/ranks by construction (`digit_onehot` and
+    `lane_onehot` mask them), never by relying on a fill value happening to
+    miss a bin.
+  * Kernels run compiled on TPU and in interpret mode elsewhere
+    (`default_interpret`; override with REPRO_PALLAS_INTERPRET=0/1).
 """
 from __future__ import annotations
 
 import os
 
 import jax
+from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
 LANES = 128
@@ -52,10 +60,7 @@ def default_interpret() -> bool:
             f"REPRO_PALLAS_INTERPRET={env!r} is not a recognized value; "
             f"allowed: {'/'.join(_INTERPRET_TRUE)} (interpret) or "
             f"{'/'.join(_INTERPRET_FALSE)} (compiled)")
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return True
+    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -73,6 +78,14 @@ def digit_onehot(x: jax.Array, num_bins: int) -> jax.Array:
     explicitly — excluded by construction, not by -1 never matching."""
     bins = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], num_bins), 1)
     return ((x[:, None] == bins) & (x[:, None] >= 0)).astype(jnp.int32)
+
+
+def lane_onehot(row: jax.Array, num_bins: int) -> jax.Array:
+    """(1, L) int digits -> (num_bins, L) 0/1 int32 one-hot: `digit_onehot`
+    transposed, with bins along sublanes so a lane tile of digits expands
+    without leaving 2-D. Negative digits (padding) get an all-zero column."""
+    bins = jax.lax.broadcasted_iota(jnp.int32, (num_bins, row.shape[-1]), 0)
+    return ((row == bins) & (row >= 0)).astype(jnp.int32)
 
 
 def pad_to(x: jax.Array, multiple: int, fill=0) -> jax.Array:
@@ -93,7 +106,7 @@ def digit_lane_blocks(digits: jax.Array, block_rows: int) -> jax.Array:
     """The one pad-and-tile path for digit arrays entering histogram/rank
     kernels: (n,) -> (grid*block_rows, 128) with every padding slot —
     lane padding and grid padding alike — filled with PAD_DIGIT. Pairs with
-    `digit_onehot`, which drops those rows by construction."""
+    `lane_onehot`, which drops those slots by construction."""
     d2 = as_lanes(digits, fill=PAD_DIGIT)
     rows = d2.shape[0]
     grid = ceil_div(rows, block_rows)
@@ -101,29 +114,15 @@ def digit_lane_blocks(digits: jax.Array, block_rows: int) -> jax.Array:
                    constant_values=PAD_DIGIT)
 
 
-def split_u32_hi_lo(x: jax.Array):
-    """int32/uint32 -> (hi16, lo16) as f32, exactly representable."""
-    u = x.astype(jnp.uint32)
-    hi = (u >> 16).astype(jnp.float32)
-    lo = (u & jnp.uint32(0xFFFF)).astype(jnp.float32)
-    return hi, lo
-
-
-def combine_u32_hi_lo(hi: jax.Array, lo: jax.Array, dtype=jnp.int32):
-    u = (hi.astype(jnp.uint32) << 16) | lo.astype(jnp.uint32)
-    return u.astype(dtype)
-
-
-def exact_onehot_matmul_i32(onehot_f32: jax.Array, values_i32: jax.Array) -> jax.Array:
-    """(T, W) one-hot @ (W,) int32 -> (T,) int32, exact via hi/lo split.
-
-    Turns a gather into MXU work — the TPU replacement for per-thread
-    random loads (DESIGN.md §2)."""
-    hi, lo = split_u32_hi_lo(values_i32)
-    out_hi = onehot_f32 @ hi
-    out_lo = onehot_f32 @ lo
-    return combine_u32_hi_lo(out_hi, out_lo, values_i32.dtype)
-
-
 def ceil_div(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def tile_onehot_sum(x_ref, num_bins: int, acc: jax.Array) -> jax.Array:
+    """acc + the lane_onehot of every row of the (rows, 128) digit tile in
+    `x_ref`: a (num_bins, 128) matrix whose lane-sum is the tile's
+    histogram. The histogram kernels' shared inner loop."""
+    def row(r, a):
+        return a + lane_onehot(x_ref[pl.ds(r, 1), :], num_bins)
+
+    return jax.lax.fori_loop(0, x_ref.shape[0], row, acc)

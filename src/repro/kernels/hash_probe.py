@@ -7,7 +7,12 @@ streams probe keys against it. TPU mapping (DESIGN.md §2):
   probe stream          ->  (1, capS) probe sub-block (the paper's probe-side
                             sub-partition decomposition, which is also its
                             load-balancing step)
-  SIMT probe loop       ->  one (capS x capR) vectorized equality
+  SIMT probe loop       ->  one (capR x capS) vectorized equality: the build
+                            block transposed to a column against the probe
+                            row, reduced over sublanes back to a row
+
+Blocks travel as (count, 1, width) arrays so every block's trailing (1,
+width) dims equal the array's (the TPU tiling rule).
 
 Probe rows are laid out partition-major and padded so every sub-block is
 capS-aligned and belongs to exactly one partition; a scalar-prefetched array
@@ -26,18 +31,23 @@ from .common import resolve_interpret
 KEY_SENTINEL = -1
 
 
+def _first_hit(bk_row, pk_row):
+    """(capR, capS) equality of the build block (as a column) against the
+    probe row; returns the first matching build slot per probe row (capR
+    where none) as a (1, capS) row, and the position iota."""
+    eq = (bk_row.T == pk_row) & (pk_row != KEY_SENTINEL)
+    iota = jax.lax.broadcasted_iota(jnp.int32, eq.shape, 0)
+    return jnp.where(eq, iota, eq.shape[0]).min(axis=0, keepdims=True), iota
+
+
 def _probe_kernel(part_ref, off_ref, probe_ref, bkeys_ref, vid_ref, hit_ref):
     i = pl.program_id(0)
-    pk = probe_ref[0]  # (capS,)
-    bk = bkeys_ref[0]  # (capR,)
-    cap_r = bk.shape[0]
-    eq = (pk[:, None] == bk[None, :]) & (pk[:, None] != KEY_SENTINEL)
-    iota = jax.lax.broadcasted_iota(jnp.int32, eq.shape, 1)
-    hitpos = jnp.where(eq, iota, cap_r).min(axis=1)
-    matched = hitpos < cap_r
+    bk = bkeys_ref[...]  # (1, capR)
+    hitpos, _ = _first_hit(bk, probe_ref[...])
+    matched = hitpos < bk.shape[1]
     base = off_ref[part_ref[i]]
-    vid_ref[0, :] = jnp.where(matched, base + hitpos, -1)
-    hit_ref[0, :] = matched.astype(jnp.int32)
+    vid_ref[...] = jnp.where(matched, base + hitpos, -1)
+    hit_ref[...] = matched.astype(jnp.int32)
 
 
 def hash_probe_pallas(
@@ -52,28 +62,27 @@ def hash_probe_pallas(
     partitioned build array (or -1) and 0/1 hit flags."""
     B, capS = probe_blocks.shape
     P, capR = bkeys.shape
+    probe_row = pl.BlockSpec((None, 1, capS), lambda i, part, off: (i, 0, 0))
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, capS), lambda i, part, off: (i, 0)),
-            pl.BlockSpec((1, capR), lambda i, part, off: (part[i], 0)),
+            probe_row,
+            pl.BlockSpec((None, 1, capR), lambda i, part, off: (part[i], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, capS), lambda i, part, off: (i, 0)),
-            pl.BlockSpec((1, capS), lambda i, part, off: (i, 0)),
-        ],
+        out_specs=[probe_row, probe_row],
     )
     vid, hit = pl.pallas_call(
         _probe_kernel,
         grid_spec=spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, capS), jnp.int32),
-            jax.ShapeDtypeStruct((B, capS), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, capS), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, capS), jnp.int32),
         ],
         interpret=resolve_interpret(interpret),
-    )(block_part.astype(jnp.int32), off_r.astype(jnp.int32), probe_blocks, bkeys)
-    return vid, hit
+    )(block_part.astype(jnp.int32), off_r.astype(jnp.int32),
+      probe_blocks.reshape(B, 1, capS), bkeys.reshape(P, 1, capR))
+    return vid.reshape(B, capS), hit.reshape(B, capS)
 
 
 # ---------------------------------------------------------------------------
@@ -83,7 +92,7 @@ def _probe_agg_kernel(part_ref, probe_ref, gk_ref, pv_ref, bkeys_ref,
                       bvals_ref, pk_ref, ps_ref, pc_ref, *, col_sides):
     """One probe sub-block: match finding (vectorized equality against the
     co-partition's build block) immediately followed by tile-local grouped
-    aggregation — both as matmuls, the §2 scatter-free mapping.
+    aggregation as one-hot matmuls — the §2 scatter-free mapping.
 
     Instead of writing (vid, hit) per row for a later materialization pass,
     the kernel reduces the tile to at most one (group key, partial sums,
@@ -94,39 +103,40 @@ def _probe_agg_kernel(part_ref, probe_ref, gk_ref, pv_ref, bkeys_ref,
     those slots drives the reduction matmuls.
 
     `col_sides` (static) maps each output column to its value source:
-    ("probe", j) reads pv_ref[0, j]; ("build", j) fetches the matched build
-    value from bvals_ref[0, j] via a one-hot matmul over the hit positions.
+    ("probe", j) reads row j of pv_ref; ("build", j) fetches the matched
+    build value from row j of bvals_ref via a masked sum at the hit
+    positions.
     Match finding and group assignment run ONCE per tile no matter how many
     aggregate columns ride the pass."""
     del part_ref  # consumed by the BlockSpec index maps only
-    pk = probe_ref[0]  # (capS,) probe join keys
-    gk = gk_ref[0]  # (capS,) probe group keys
-    bk = bkeys_ref[0]  # (capR,) build block keys
-    cap_r = bk.shape[0]
-    cap_s = pk.shape[0]
-    eq = (pk[:, None] == bk[None, :]) & (pk[:, None] != KEY_SENTINEL)
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, eq.shape, 1)
-    hitpos = jnp.where(eq, iota_r, cap_r).min(axis=1)
-    matched = hitpos < cap_r
+    pk = probe_ref[...]  # (1, capS) probe join keys
+    gk = gk_ref[...]  # (1, capS) probe group keys
+    cap_s = pk.shape[1]
+    hitpos, iota_r = _first_hit(bkeys_ref[...], pk)
+    matched = hitpos < iota_r.shape[0]
     # one-hot of the (unique, deterministic) first hit position: fetches any
     # number of build value columns without leaving VMEM
-    oh_b = (iota_r == hitpos[:, None]).astype(jnp.float32)
+    at_hit = iota_r == hitpos  # (capR, capS)
     gke = jnp.where(matched, gk, KEY_SENTINEL)
     # slot of row i = first row in the tile with the same group key
-    eqg = (gke[:, None] == gke[None, :]) & (gke[:, None] != KEY_SENTINEL)
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (cap_s, cap_s), 1)
-    rep = jnp.where(eqg, iota_s, cap_s).min(axis=1)
-    oh = (rep[:, None] == iota_s).astype(jnp.float32)  # (rows, slots)
+    eqg = (gke.T == gke) & (gke != KEY_SENTINEL)  # (capS, capS), symmetric
+    iota_s = jax.lax.broadcasted_iota(jnp.int32, (cap_s, cap_s), 0)
+    rep = jnp.where(eqg, iota_s, cap_s).min(axis=0, keepdims=True)  # (1, capS)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (cap_s, cap_s), 1)
+    oh = (rep.T == slots).astype(jnp.float32)  # (rows, slots)
     for c, (side, j) in enumerate(col_sides):
         if side == "build":
-            val = (oh_b * bvals_ref[0, j][None, :]).sum(axis=1)
+            bcol = bvals_ref[j:j + 1, :].T  # (capR, 1)
+            val = jnp.where(at_hit, bcol, 0.0).sum(axis=0, keepdims=True)
         else:
-            val = pv_ref[0, j]
-        ps_ref[0, c, :] = jnp.where(matched, val, 0.0) @ oh
-    counts = matched.astype(jnp.float32) @ oh
-    pc_ref[0, :] = counts.astype(jnp.int32)
+            val = pv_ref[j:j + 1, :]
+        ps_ref[c:c + 1, :] = jnp.dot(jnp.where(matched, val, 0.0), oh,
+                                     precision=jax.lax.Precision.HIGHEST)
+    counts = jnp.dot(matched.astype(jnp.float32), oh,
+                     precision=jax.lax.Precision.HIGHEST)
+    pc_ref[...] = counts.astype(jnp.int32)
     # slot j only ever receives rows whose group key equals gke[j]
-    pk_ref[0, :] = jnp.where(counts > 0, gke, KEY_SENTINEL)
+    pk_ref[...] = jnp.where(counts > 0, gke, KEY_SENTINEL)
 
 
 def probe_agg_pallas(
@@ -151,34 +161,32 @@ def probe_agg_pallas(
     Cp = pv_blocks.shape[1]
     Cb = bvals.shape[1]
     C = len(col_sides)
+    def probe_side(rows):
+        return pl.BlockSpec((None, rows, capS), lambda i, part: (i, 0, 0))
+
+    def build_side(rows):
+        return pl.BlockSpec((None, rows, capR), lambda i, part: (part[i], 0, 0))
+
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, capS), lambda i, part: (i, 0)),
-            pl.BlockSpec((1, capS), lambda i, part: (i, 0)),
-            pl.BlockSpec((1, Cp, capS), lambda i, part: (i, 0, 0)),
-            pl.BlockSpec((1, capR), lambda i, part: (part[i], 0)),
-            pl.BlockSpec((1, Cb, capR), lambda i, part: (part[i], 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, capS), lambda i, part: (i, 0)),
-            pl.BlockSpec((1, C, capS), lambda i, part: (i, 0, 0)),
-            pl.BlockSpec((1, capS), lambda i, part: (i, 0)),
-        ],
+        in_specs=[probe_side(1), probe_side(1), probe_side(Cp),
+                  build_side(1), build_side(Cb)],
+        out_specs=[probe_side(1), probe_side(C), probe_side(1)],
     )
     pk, ps, pc = pl.pallas_call(
         functools.partial(_probe_agg_kernel, col_sides=tuple(col_sides)),
         grid_spec=spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, capS), gk_blocks.dtype),
+            jax.ShapeDtypeStruct((B, 1, capS), gk_blocks.dtype),
             jax.ShapeDtypeStruct((B, C, capS), jnp.float32),
-            jax.ShapeDtypeStruct((B, capS), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, capS), jnp.int32),
         ],
         interpret=resolve_interpret(interpret),
-    )(block_part.astype(jnp.int32), probe_blocks, gk_blocks,
-      pv_blocks.astype(jnp.float32), bkeys, bvals.astype(jnp.float32))
-    return pk, ps, pc
+    )(block_part.astype(jnp.int32), probe_blocks.reshape(B, 1, capS),
+      gk_blocks.reshape(B, 1, capS), pv_blocks.astype(jnp.float32),
+      bkeys.reshape(P, 1, capR), bvals.astype(jnp.float32))
+    return pk.reshape(B, capS), ps, pc.reshape(B, capS)
 
 
 def layout_probe_blocks(

@@ -1,13 +1,14 @@
 """Radix-histogram Pallas kernel.
 
 Grid: sequential row-blocks of the digit array (viewed as (rows, 128) lanes).
-Each step builds a block-local histogram by summing a one-hot expansion
-(dense VPU/MXU work — the TPU replacement for shared-memory atomics,
-DESIGN.md §2) and accumulates into the single (1, num_bins) output block,
-which stays VMEM-resident across the whole grid.
+Each step expands every 128-lane row of its tile into a (num_bins, 128)
+one-hot (bins along sublanes: dense VPU work — the TPU replacement for
+shared-memory atomics, DESIGN.md §2) and adds it into the single
+(num_bins, 128) output block, which stays VMEM-resident across the whole
+grid. The wrapper folds the 128 lane partials into the final counts.
 
-The one-hot core (`common.digit_onehot`) is shared with the per-block
-histogram and rank kernels in radix_partition.py; padding rows carry
+The one-hot core (`common.lane_onehot`) is shared with the per-block
+histogram and rank kernels in radix_partition.py; padding slots carry
 PAD_DIGIT and are excluded from the counts by construction.
 """
 from __future__ import annotations
@@ -18,7 +19,7 @@ import jax
 from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
-from .common import LANES, digit_lane_blocks, digit_onehot, resolve_interpret
+from .common import LANES, digit_lane_blocks, resolve_interpret, tile_onehot_sum
 
 
 def _hist_kernel(num_bins: int, x_ref, o_ref):
@@ -26,16 +27,14 @@ def _hist_kernel(num_bins: int, x_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].reshape(-1)  # (rows*128,)
-    oh = digit_onehot(x, num_bins)
-    o_ref[...] += oh.sum(axis=0, keepdims=True)
+    o_ref[...] = tile_onehot_sum(x_ref, num_bins, o_ref[...])
 
 
 def histogram_pallas(
     digits: jax.Array,
     num_bins: int,
     *,
-    block_rows: int = 8,
+    block_rows: int = 64,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Counts per digit. digits int32; padding/pad rows (PAD_DIGIT or any
@@ -47,8 +46,8 @@ def histogram_pallas(
         functools.partial(_hist_kernel, num_bins),
         grid=(grid,),
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, num_bins), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, num_bins), jnp.int32),
+        out_specs=pl.BlockSpec((num_bins, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((num_bins, LANES), jnp.int32),
         interpret=resolve_interpret(interpret),
     )(d2)
-    return out[0]
+    return out.sum(axis=1)

@@ -13,9 +13,10 @@ tile boundaries chooses each tile's window; tiles whose span exceeds the
 window fall back to XLA searchsorted). Probe tiles are sorted, so windows
 are monotone — sequential HBM traffic, the same clustering argument as GFTR.
 
-Layout: build keys padded to (n_wb + 1, W) with an INT_MAX tail block; each
-grid step sees two consecutive W-blocks (an aligned 2W window) selected by a
-scalar-prefetched window index.
+Layout: build keys padded to (n_wb + 1, 1, W) with an INT_MAX tail block;
+each grid step sees two consecutive W-blocks (an aligned 2W window)
+selected by a scalar-prefetched window index. The window is compared as a
+(W, 1) column against the (1, T) probe row, WINDOW_CHUNK rows at a time.
 """
 from __future__ import annotations
 
@@ -27,17 +28,21 @@ from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
 from .common import ceil_div, resolve_interpret
+from .gather import WINDOW_CHUNK
 
 INT_MAX = jnp.iinfo(jnp.int32).max
 
 
 def _lb_kernel(window_rows: int, w_ref, probe_ref, lo_ref, hi_ref, out_ref):
     i = pl.program_id(0)
-    win_start = w_ref[i] * window_rows
-    window = jnp.concatenate([lo_ref[0], hi_ref[0]])  # (2W,) sorted
-    p = probe_ref[0]  # (T,)
-    lt = (window[None, :] < p[:, None]).astype(jnp.int32)  # (T, 2W)
-    out_ref[0, :] = win_start + lt.sum(axis=1)
+    p = probe_ref[...]  # (1, T)
+    below = jnp.zeros(p.shape, jnp.int32)
+    for half in (lo_ref, hi_ref):
+        col = half[...].T  # (W, 1) sorted
+        for c in range(0, window_rows, WINDOW_CHUNK):
+            lt = col[c:c + WINDOW_CHUNK] < p
+            below = below + lt.astype(jnp.int32).sum(axis=0, keepdims=True)
+    out_ref[...] = w_ref[i] * window_rows + below
 
 
 def lower_bound_windowed_pallas(
@@ -55,11 +60,11 @@ def lower_bound_windowed_pallas(
     n_b, n_p = build_sorted.shape[0], probe_sorted.shape[0]
     n_wb = ceil_div(n_b, window_rows)
     bpad = jnp.full((n_wb * window_rows - n_b + window_rows,), INT_MAX, build_sorted.dtype)
-    build2 = jnp.concatenate([build_sorted, bpad]).reshape(n_wb + 1, window_rows)
+    build3 = jnp.concatenate([build_sorted, bpad]).reshape(n_wb + 1, 1, window_rows)
 
     n_tiles = ceil_div(n_p, tile)
     ppad = jnp.full((n_tiles * tile - n_p,), INT_MAX, probe_sorted.dtype)
-    probe2 = jnp.concatenate([probe_sorted, ppad]).reshape(n_tiles, tile)
+    probe3 = jnp.concatenate([probe_sorted, ppad]).reshape(n_tiles, 1, tile)
 
     win_idx = jnp.clip(win_idx.astype(jnp.int32), 0, n_wb - 1)
 
@@ -67,16 +72,16 @@ def lower_bound_windowed_pallas(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, w: (i, 0)),
-            pl.BlockSpec((1, window_rows), lambda i, w: (w[i], 0)),
-            pl.BlockSpec((1, window_rows), lambda i, w: (w[i] + 1, 0)),
+            pl.BlockSpec((None, 1, tile), lambda i, w: (i, 0, 0)),
+            pl.BlockSpec((None, 1, window_rows), lambda i, w: (w[i], 0, 0)),
+            pl.BlockSpec((None, 1, window_rows), lambda i, w: (w[i] + 1, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile), lambda i, w: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, tile), lambda i, w: (i, 0, 0)),
     )
     out = pl.pallas_call(
         functools.partial(_lb_kernel, window_rows),
         grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, tile), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tile), jnp.int32),
         interpret=resolve_interpret(interpret),
-    )(win_idx, probe2, build2, build2)
+    )(win_idx, probe3, build3, build3)
     return out.reshape(-1)[:n_p]

@@ -54,19 +54,19 @@ KEY_SENTINEL = -1
 
 
 def _pallas_arm(site: str, pallas_fn, xla_fn):
-    """Run a dispatch's pallas arm with graceful degradation: if the arm
-    raises (real kernel failure, unsupported backend, or an armed
-    `REPRO_FAULTS=pallas:<site>` injection), fall back to the bit-identical
-    XLA arm and record the event (DESIGN.md §13's pallas -> xla chain; the
-    dense-jnp reference IS the xla arm here, so the chain terminates).
+    """Run a dispatch's pallas arm; an armed `REPRO_FAULTS=pallas:<site>`
+    injection instead runs the bit-identical XLA arm and records the event
+    (DESIGN.md §13's pallas -> xla chain; the dense-jnp reference IS the xla
+    arm here, so the chain terminates). A real kernel failure — one that
+    does not lower or compile for the device — propagates: silently running
+    the reference would hide that the device path is broken.
 
-    Zero-overhead contract: with no faults active and a healthy kernel this
-    is one host-side call through `pallas_fn` — the try/except and the
-    fault check contribute nothing to the traced jaxpr."""
+    Zero-overhead contract: with no faults active this is one host-side
+    call through `pallas_fn` — the fault check contributes nothing to the
+    traced jaxpr."""
     try:
         faults.check_pallas(site)
-        return pallas_fn()
-    except Exception as e:  # noqa: BLE001 — any arm failure degrades
+    except faults.FaultInjected as e:
         from repro.obs import metrics  # deferred: kernels stay obs-free
         from repro.resilience import escalation
 
@@ -75,6 +75,7 @@ def _pallas_arm(site: str, pallas_fn, xla_fn):
         escalation.record_degradation(
             f"kernels.{site}", f"pallas arm failed: {type(e).__name__}: {e}")
         return xla_fn()
+    return pallas_fn()
 
 
 # ---------------------------------------------------------------------------

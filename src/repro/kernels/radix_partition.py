@@ -10,9 +10,11 @@ by construction — the property PHJ-OM needs):
   pass B (rank):      per-element destination index
             dest[i] = base[block, digit] + rank_within_block(i)
 
-The within-block stable rank is a cumsum over the one-hot digit expansion —
-dense VPU work; no scatter ever happens inside a kernel. The actual data
-movement is then a single XLA gather with the inverted permutation.
+The within-block stable rank is a running count over the one-hot digit
+expansion of each 128-lane row (bins along sublanes), with the in-row prefix
+taken by one 0/1 matmul — dense VPU/MXU work; no scatter ever happens inside
+a kernel. The actual data movement is then a single XLA gather with the
+inverted permutation.
 
 Multi-pass (`partition_plan_pallas`): fan-outs past one pass's bin budget
 compose LSD passes of <= `pass_bits` bits each — pass k ranks bits
@@ -37,50 +39,89 @@ import jax
 from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
-from .common import LANES, ceil_div, digit_lane_blocks, digit_onehot, resolve_interpret
+from .common import (LANES, ceil_div, digit_lane_blocks, digit_onehot, lane_onehot,
+                     resolve_interpret, tile_onehot_sum)
 
 
 def _block_hist_kernel(num_bins: int, x_ref, o_ref):
-    x = x_ref[...].reshape(-1)
-    oh = digit_onehot(x, num_bins)
-    o_ref[...] = oh.sum(axis=0, keepdims=True)
+    # grid step i owns column i % 128 of the (num_bins, 128) output block
+    # shared by 128 consecutive steps (block-histograms are stored
+    # transposed: digits along sublanes, blocks along lanes)
+    i = pl.program_id(0)
+
+    @pl.when(i % LANES == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    acc = tile_onehot_sum(x_ref, num_bins, jnp.zeros(o_ref.shape, jnp.int32))
+    col = acc.sum(axis=1, keepdims=True)  # (num_bins, 1)
+    mine = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1) == i % LANES
+    o_ref[...] = jnp.where(mine, col, o_ref[...])
 
 
-def block_histograms_pallas(
-    digits: jax.Array, num_bins: int, *, block_rows: int = 8,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """(num_blocks, num_bins) per-block histograms. Padding rows (PAD_DIGIT)
-    are excluded by construction — `digit_onehot` masks negative digits out
-    of the one-hot, so no fill value can ever be counted into a bin."""
-    d2 = digit_lane_blocks(digits, block_rows)
+def _block_histograms_t(d2, num_bins: int, block_rows: int, interpret: bool):
+    """(num_bins, grid rounded up to 128) per-block histograms of the lane
+    blocks `d2`, transposed; columns past the grid are zero."""
     grid = d2.shape[0] // block_rows
     return pl.pallas_call(
         functools.partial(_block_hist_kernel, num_bins),
         grid=(grid,),
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, num_bins), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid, num_bins), jnp.int32),
-        interpret=resolve_interpret(interpret),
+        out_specs=pl.BlockSpec((num_bins, LANES), lambda i: (0, i // LANES)),
+        out_shape=jax.ShapeDtypeStruct(
+            (num_bins, ceil_div(grid, LANES) * LANES), jnp.int32),
+        interpret=interpret,
     )(d2)
 
 
+def block_histograms_pallas(
+    digits: jax.Array, num_bins: int, *, block_rows: int = 64,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """(num_blocks, num_bins) per-block histograms. Padding rows (PAD_DIGIT)
+    are excluded by construction — `lane_onehot` masks negative digits out
+    of the one-hot, so no fill value can ever be counted into a bin."""
+    d2 = digit_lane_blocks(digits, block_rows)
+    bh = _block_histograms_t(d2, num_bins, block_rows,
+                             resolve_interpret(interpret))
+    return bh[:, :d2.shape[0] // block_rows].T
+
+
 def _rank_kernel(num_bins: int, x_ref, base_ref, o_ref):
-    x = x_ref[...].reshape(-1)  # (T,)
-    oh = digit_onehot(x, num_bins)  # (T, G); pad rows all-zero
-    excl = jnp.cumsum(oh, axis=0) - oh  # exclusive within-block rank per digit
-    # own-column selection without gather: elementwise mask + row-sum
-    rank = (excl * oh).sum(axis=1)
-    base = (base_ref[...][0][None, :] * oh).sum(axis=1)  # base[digit_i]
-    dest = jnp.where(x >= 0, base + rank, -1)
-    o_ref[...] = dest.reshape(o_ref.shape)
+    i = pl.program_id(0)
+    G = num_bins
+    # this block's base offsets: column i % 128 of the transposed base block,
+    # kept broadcast over lanes as the running per-digit position
+    mine = jax.lax.broadcasted_iota(jnp.int32, base_ref.shape, 1) == i % LANES
+    start = jnp.where(mine, base_ref[...], 0).sum(axis=1, keepdims=True)
+    # [strict upper triangle | ones]: one matmul gives each row's exclusive
+    # within-row rank per digit (lanes 0..127) and the row's digit totals
+    # broadcast over lanes (lanes 128..255); 0/1 inputs, counts <= 128, so
+    # bf16 operands with f32 accumulation are exact
+    src = jax.lax.broadcasted_iota(jnp.int32, (LANES, 2 * LANES), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (LANES, 2 * LANES), 1)
+    scan_op = ((src < dst) | (dst >= LANES)).astype(jnp.bfloat16)
+
+    def row(r, pos):
+        x = x_ref[pl.ds(r, 1), :]  # (1, 128)
+        oh = lane_onehot(x, G)  # (G, 128); pad lanes all-zero
+        pt = jnp.dot(oh.astype(jnp.bfloat16), scan_op,
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+        # own-digit selection without gather: masked sublane sum
+        dest = jnp.where(oh > 0, pos + pt[:, :LANES], 0).sum(axis=0,
+                                                             keepdims=True)
+        o_ref[pl.ds(r, 1), :] = jnp.where(x >= 0, dest, -1)
+        return pos + pt[:, LANES:]
+
+    jax.lax.fori_loop(0, x_ref.shape[0], row,
+                      jnp.broadcast_to(start, (G, LANES)))
 
 
 def partition_ranks_pallas(
     digits: jax.Array,
     num_bins: int,
     *,
-    block_rows: int = 8,
+    block_rows: int = 64,
     interpret: bool | None = None,
 ):
     """Destination index per element for the stable partition (one pass).
@@ -90,23 +131,19 @@ def partition_ranks_pallas(
     (PAD_DIGIT padding) get dest -1 and never occupy a position."""
     n = digits.shape[0]
     interpret = resolve_interpret(interpret)
-    bh = block_histograms_pallas(digits, num_bins, block_rows=block_rows,
-                                 interpret=interpret)
-    sizes = bh.sum(axis=0)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)[:-1].astype(jnp.int32)])
-    # base[b, g] = offsets[g] + sum_{b' < b} bh[b', g]
-    prev = jnp.cumsum(bh, axis=0) - bh
-    base = (offsets[None, :] + prev).astype(jnp.int32)
-
     d2 = digit_lane_blocks(digits, block_rows)
     grid = d2.shape[0] // block_rows
+    bh = _block_histograms_t(d2, num_bins, block_rows, interpret)
+    sizes = bh.sum(axis=1)
+    offsets = (jnp.cumsum(sizes) - sizes).astype(jnp.int32)
+    # base[g, b] = offsets[g] + sum_{b' < b} bh[g, b']
+    base = (offsets[:, None] + jnp.cumsum(bh, axis=1) - bh).astype(jnp.int32)
     dest = pl.pallas_call(
         functools.partial(_rank_kernel, num_bins),
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, num_bins), lambda i: (i, 0)),
+            pl.BlockSpec((num_bins, LANES), lambda i: (0, i // LANES)),
         ],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((d2.shape[0], LANES), jnp.int32),

@@ -71,7 +71,8 @@ def segsum_partials(sorted_keys: jax.Array, values: jax.Array, tile: int):
     lgid = jnp.cumsum(bnd.astype(jnp.int32), axis=1) - 1
     lgid = jnp.where(valid, lgid, tile)
     oh = jax.nn.one_hot(lgid, tile, dtype=jnp.float32)  # (T, tile, tile)
-    psums = jnp.einsum("tb,tbg->tg", v.astype(jnp.float32), oh)
+    # a masked sum over rows, as the kernel does: exact f32 adds in row order
+    psums = jnp.where(oh > 0, v.astype(jnp.float32)[:, :, None], 0.0).sum(axis=1)
     pcounts = jnp.einsum("tbg->tg", oh)
     T = k.shape[0]
     pkeys = (

@@ -14,30 +14,37 @@ import jax
 from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
-from .common import ceil_div, combine_u32_hi_lo, resolve_interpret, split_u32_hi_lo
+from .common import ceil_div, resolve_interpret
 
 KEY_SENTINEL = -1
 
 
 def _segsum_kernel(k_ref, v_ref, pk_ref, ps_ref, pc_ref):
-    k = k_ref[0]  # (T,) sorted within tile
-    v = v_ref[0].astype(jnp.float32)
-    T = k.shape[0]
+    k = k_ref[...]  # (1, T) sorted within tile
+    v = v_ref[...].astype(jnp.float32)
+    T = k.shape[1]
     valid = k != KEY_SENTINEL
-    prev = jnp.concatenate([jnp.full((1,), KEY_SENTINEL, k.dtype), k[:-1]])
+    rows = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    kc = k.T  # (T, 1)
+    # prev[t] = k[t-1] (KEY_SENTINEL at t=0): masked sum over the key column
+    prev = jnp.where(rows == cols - 1, kc, 0).sum(axis=0, keepdims=True)
+    prev = jnp.where(cols[:1] == 0, KEY_SENTINEL, prev)
     bnd = (k != prev) & valid
-    lgid = jnp.cumsum(bnd.astype(jnp.int32)) - 1
-    lgid = jnp.where(valid, lgid, T)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    oh = (lgid[:, None] == iota).astype(jnp.float32)  # (rows T, groups T)
-    ps_ref[0, :] = v @ oh
-    counts = jnp.ones((T,), jnp.float32) @ oh
-    pc_ref[0, :] = counts.astype(jnp.int32)
-    # group keys via run-head selection (single 1 per column -> exact matmul)
-    head = oh * bnd[:, None].astype(jnp.float32)
-    hi16, lo16 = split_u32_hi_lo(k)
-    pk = combine_u32_hi_lo(head.T @ hi16, head.T @ lo16, k.dtype)
-    pk_ref[0, :] = jnp.where(counts > 0, pk, KEY_SENTINEL)
+    # local group id = inclusive count of run heads - 1, as a 0/1 matmul
+    # against the upper triangle (bf16 operands, f32 accumulation: exact)
+    heads = jnp.dot(bnd.astype(jnp.bfloat16), (rows <= cols).astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+    lgid = jnp.where(valid, heads.astype(jnp.int32) - 1, T)
+    oh = lgid.T == cols  # (rows T, groups T)
+    # masked sums over rows: the reference's exact expression and order
+    ps_ref[...] = jnp.where(oh, v.T, 0.0).sum(axis=0, keepdims=True)
+    counts = oh.astype(jnp.int32).sum(axis=0, keepdims=True)
+    pc_ref[...] = counts
+    # group keys via run-head selection (one head per group column)
+    head = oh & (bnd.astype(jnp.int32).T > 0)
+    pk = jnp.where(head, kc, 0).sum(axis=0, keepdims=True)
+    pk_ref[...] = jnp.where(counts > 0, pk, KEY_SENTINEL)
 
 
 def segsum_partials_pallas(
@@ -53,26 +60,20 @@ def segsum_partials_pallas(
     n_tiles = ceil_div(n, tile)
     kp = jnp.concatenate(
         [sorted_keys, jnp.full((n_tiles * tile - n,), KEY_SENTINEL, sorted_keys.dtype)]
-    ).reshape(n_tiles, tile)
+    ).reshape(n_tiles, 1, tile)
     vp = jnp.concatenate(
         [values, jnp.zeros((n_tiles * tile - n,), values.dtype)]
-    ).reshape(n_tiles, tile)
+    ).reshape(n_tiles, 1, tile)
+    row = pl.BlockSpec((None, 1, tile), lambda i: (i, 0, 0))
     pk, ps, pc = pl.pallas_call(
         _segsum_kernel,
         grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (i, 0)),
-        ],
+        in_specs=[row, row],
+        out_specs=[row, row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, tile), sorted_keys.dtype),
-            jax.ShapeDtypeStruct((n_tiles, tile), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, tile), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, tile), sorted_keys.dtype),
+            jax.ShapeDtypeStruct((n_tiles, 1, tile), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, 1, tile), jnp.int32),
         ],
         interpret=resolve_interpret(interpret),
     )(kp, vp)

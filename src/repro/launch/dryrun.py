@@ -169,8 +169,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
         mem["live_bytes_per_device"] = int(live)
         mem["fits_v5e_16GB"] = bool(live < RL.HBM_CAP)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     cost = {"hlo_flops_per_device_body_once": float(ca.get("flops", 0.0)),
             "hlo_bytes_accessed_per_device_body_once": float(ca.get("bytes accessed", 0.0))}
 

@@ -9,13 +9,8 @@ import jax
 
 
 def _mk(shape: tuple, axes: tuple):
-    # jax.sharding.AxisType (explicit-sharding API) only exists on jax
-    # >= 0.5; every axis is Auto there by default, so omitting it on older
-    # versions is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,8 +24,10 @@ def main(argv: list[str]) -> int:
     out = "BENCH_serve.json"
     if "--out" in argv:
         out = argv[argv.index("--out") + 1]
+    from repro import compile_cache
     from repro.serve.chaos import run_chaos
 
+    compile_cache.enable()
     report = run_chaos(smoke="--smoke" in argv)
     with open(out, "w") as f:
         json.dump(report, f, indent=2, sort_keys=True)

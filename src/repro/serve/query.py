@@ -75,13 +75,6 @@ from repro.resilience import escalation, faults
 MIN_BUCKET = 64  # smallest capacity bucket (one lane-rounded tile)
 
 
-class CapacitySaturated(RuntimeError):
-    """A root operator's valid count reached its static capacity: the
-    result is *suspected* truncated (capacity clamping makes real
-    truncation indistinguishable from an exact fit), so the run is
-    treated as failed and retried with more headroom."""
-
-
 def bucket_rows(n: int) -> int:
     """Power-of-two capacity bucket for an ``n``-row relation (>= MIN_BUCKET).
     Padding to the bucket means at most 2x wasted rows, in exchange for a
@@ -563,7 +556,7 @@ class QueryServer:
         metrics.counter("qserve.fast_runs").inc()
         if _saturated(entry.plan.root, count):
             metrics.counter("qserve.saturations").inc()
-            raise CapacitySaturated(
+            raise executor.CapacitySaturated(
                 f"root count {int(count)} filled capacity "
                 f"{entry.plan.root.capacity}")
         return out, count
@@ -580,9 +573,9 @@ class QueryServer:
             try:
                 out, count = executor.run(plan, padded, counts=counts,
                                           jit=False)
-            except executor._NON_DEGRADABLE:
-                raise
             except Exception as e:  # noqa: BLE001 — escalate a level
+                if not executor.degradable(e):
+                    raise
                 last_exc = e
                 metrics.counter("qserve.safe_escalations").inc()
                 req.escalations += 1
@@ -594,7 +587,7 @@ class QueryServer:
             entry.safe_level = level
             metrics.counter("qserve.safe_runs").inc()
             return out, count
-        raise CapacitySaturated(
+        raise executor.CapacitySaturated(
             f"safe path exhausted at level {self.max_safe_level}"
         ) from last_exc
 
@@ -615,8 +608,6 @@ class QueryServer:
             try:
                 out, count = executor.run_morsels(
                     entry.plan, padded, counts=counts, factor=factor)
-            except executor._NON_DEGRADABLE:
-                raise
             except Exception as e:  # noqa: BLE001 — shrink and retry
                 if not MB.is_memory_error(e):
                     raise
@@ -642,6 +633,16 @@ class QueryServer:
             return self._run_chunked_safe(entry, req)
         return self._run_safe(entry, req)
 
+    def _hard_fail(self, req: QueryRequest, exc: Exception,
+                   t0: float) -> None:
+        """A failure no degraded plan can fix (a bug, a kernel that does
+        not lower or compile): fail this request loudly without touching
+        the breaker or the safe chain — a slower plan would mask it — and
+        keep the rest of the tick's batch running."""
+        metrics.counter("qserve.hard_failures").inc()
+        req.exec_wall_s = time.perf_counter() - t0
+        self._finish(req, "failed", f"{type(exc).__name__}: {exc}")
+
     def _run_one(self, req: QueryRequest) -> None:
         entry = self.cache[req.signature]
         breaker = self.breakers[req.signature]
@@ -654,18 +655,20 @@ class QueryServer:
                         out = self._run_fast(entry, req)
                     else:
                         out = self._run_safe(entry, req)
-                except executor._NON_DEGRADABLE:
-                    raise  # programming errors surface; never quarantine
                 except Exception as e:  # noqa: BLE001 — contain to request
+                    if not executor.degradable(e):
+                        self._hard_fail(req, e, t0)
+                        return
                     if route == "fast":
                         breaker.record_fast_failure(self.tick)
                         metrics.counter("qserve.fast_failures").inc()
                         try:
                             out = self._fallback(entry, req, e)
                             route = "fast+safe"
-                        except executor._NON_DEGRADABLE:
-                            raise
                         except Exception as e2:  # noqa: BLE001
+                            if not executor.degradable(e2):
+                                self._hard_fail(req, e2, t0)
+                                return
                             breaker.record_safe_failure(self.tick)
                             metrics.counter("qserve.failed").inc()
                             req.exec_wall_s = time.perf_counter() - t0
@@ -684,8 +687,8 @@ class QueryServer:
                         breaker.record_fast_success(self.tick)
         finally:
             # the bytes ticket is held from admission to HERE — every exit
-            # path (success, failure, even a surfacing programming error)
-            # releases it, so reservations can never leak
+            # path (success, failure, even an unexpected raise) releases
+            # it, so reservations can never leak
             self.budget.release(f"q{req.qid}")
         req.exec_wall_s = time.perf_counter() - t0
         req.result = out

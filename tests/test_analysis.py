@@ -192,6 +192,13 @@ def test_aliased_grid_output_trips_lint_unless_declared():
     declared = kernel_lint.lint_fn(aliased, x, allow_output_revisit=True)
     assert not any(r.violations for r in declared)
     assert declared[0].aliased_output_blocks == 1
+    # a declaration names kernel bodies: only the named one may revisit
+    assert declared[0].name.endswith("/kern")
+    named = kernel_lint.lint_fn(aliased, x, allow_output_revisit=("kern",))
+    assert not any(r.violations for r in named)
+    other = kernel_lint.lint_fn(aliased, x, allow_output_revisit=("other",))
+    assert any(isinstance(v, GridAliasViolation)
+               for r in other for v in r.violations)
 
 
 def test_materialization_bound_trips_on_fat_residency():
@@ -218,6 +225,9 @@ def test_production_kernels_lint_clean():
     # histogram's sequential accumulation is exercised AND declared
     hist = [r for r in reports if r.name.startswith("histogram")]
     assert hist and hist[0].aliased_output_blocks >= 1
+    # partition_ranks' rank kernel is not covered by the block-histogram
+    # kernel's declaration: it is linted as an injective output map
+    assert "partition_ranks/_rank_kernel" in {r.name for r in reports}
 
 
 # ---------------------------------------------------------------------------

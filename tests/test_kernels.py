@@ -42,7 +42,7 @@ def test_pad_rows_excluded_by_construction(rng):
     """Histogram/rank kernels must exclude PAD_DIGIT rows via the explicit
     mask in `digit_onehot` — any negative digit counts nowhere and gets no
     destination, however the bins are laid out."""
-    from repro.kernels.common import digit_onehot
+    from repro.kernels.common import digit_onehot, lane_onehot
     from repro.kernels.radix_partition import block_histograms_pallas
 
     d = np.asarray(rng.integers(0, 16, 100).astype(np.int32))
@@ -53,9 +53,11 @@ def test_pad_rows_excluded_by_construction(rng):
     dest, _, sizes = partition_ranks_pallas(dj, 16)
     assert int(sizes.sum()) == int((d >= 0).sum())
     assert (np.asarray(dest)[d < 0] == -1).all()
-    # the shared one-hot core masks any negative digit, not just -1
+    # the shared one-hot cores mask any negative digit, not just -1
     oh = np.asarray(digit_onehot(jnp.asarray([-5, 0, 3, -1], jnp.int32), 4))
     np.testing.assert_array_equal(oh.sum(axis=1), [0, 1, 1, 0])
+    oh_t = np.asarray(lane_onehot(jnp.asarray([[-5, 0, 3, -1]], jnp.int32), 4))
+    np.testing.assert_array_equal(oh_t, oh.T)
 
 
 def test_interpret_resolution_env_override(monkeypatch):

@@ -145,6 +145,42 @@ def test_server_admission_price_and_shedding():
     assert all(not r.error for r in reqs[:2])
 
 
+def test_server_hard_failure_fails_one_request_of_the_tick():
+    """A failure no degraded plan can fix (here a plain RuntimeError, as a
+    kernel that fails to compile raises) fails its own request loudly —
+    no safe chain, no breaker — while the rest of the tick's batch runs
+    and every bytes ticket is released."""
+    tb = make_join_tables(400, 1500, seed=23)
+    server = Q.QueryServer(slots_per_tick=2)
+    bad = Q.QueryRequest(qid=0, plan=JOIN_PLAN, tables=tb)
+    good = Q.QueryRequest(qid=1, plan=JOIN_PLAN, tables=tb)
+    real_fast = server._run_fast
+
+    def run_fast(entry, req):
+        if req is bad:
+            raise RuntimeError("kernel failed to compile")
+        return real_fast(entry, req)
+
+    server._run_fast = run_fast
+    watched = ("qserve.hard_failures", "qserve.fast_failures",
+               "qserve.safe_runs", "qserve.failed")
+    before = {c: metrics.counter(c).value for c in watched}
+    server.submit(bad)
+    server.submit(good)
+    server.step()
+    assert bad.admit_tick == good.admit_tick == 1  # one tick, one batch
+    assert bad.done and bad.error == "failed" and bad.result is None
+    assert "RuntimeError: kernel failed to compile" in bad.detail
+    assert good.done and not good.error and good.path == "fast"
+    assert canon(*good.result) == one_shot(JOIN_PLAN, tb)
+    moved = {c: metrics.counter(c).value - before[c] for c in watched}
+    assert moved == {"qserve.hard_failures": 1, "qserve.fast_failures": 0,
+                     "qserve.safe_runs": 0, "qserve.failed": 0}
+    assert server.breakers[bad.signature].failures == 0
+    assert server.budget.reserved == 0
+    assert not server.queue and not server.deferred
+
+
 def test_server_deadline_expires_on_admission_tick():
     """A queued query whose deadline lands exactly on the tick it would be
     admitted is evicted, not run: the deadline sweep precedes admission."""
