@@ -1,0 +1,10 @@
+"""aggregate_device_s: device seconds per traced query under the
+`aggregate` phase scope of the served executable: reduction arithmetic
+(run boundaries, segmented sums, block partials, combines). Each busy
+instant of the traced window goes to the innermost operation running
+then, so the five phase metrics sum to the busy time (`bench/spans.py`)."""
+import spans
+
+
+def read(record):
+    return spans.traced_phase_s(record, "aggregate")

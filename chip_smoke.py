@@ -166,9 +166,7 @@ def serve_and_check(server: QueryServer, *, seed: int, requests: int,
                    "path": req.path, "error": req.error,
                    "exec_wall_s": req.exec_wall_s,
                    "plan_wall_s": req.plan_wall_s, "drain_wall_s": drain_s,
-                   "signature": req.signature, "mismatch": "",
-                   "inputs": {n: {c: t[c].dtype for c in t.column_names}
-                              for n, t in tables.items()}}
+                   "signature": req.signature, "mismatch": ""}
             if req.result is None:
                 rec["mismatch"] = f"no result: {req.error} {req.detail}"
             else:
@@ -192,29 +190,22 @@ def serve_and_check(server: QueryServer, *, seed: int, requests: int,
 
 
 def _fast_executable_text(server: QueryServer, records: list[dict]) -> dict:
-    """Compiled text of each signature's fast-path executable, lowered for
-    the padded input shapes its requests ran with."""
-    import jax.numpy as jnp
-
-    from repro.core.table import Table
+    """Compiled text of each signature's fast-path executables: the served
+    programs the executor compiled, and cached, for its requests' shapes."""
     from repro.engine import physical as P
 
     texts = {}
-    for rec in records:
-        sig = rec["signature"]
-        if sig in texts or sig not in server.cache:
+    for sig in dict.fromkeys(rec["signature"] for rec in records):
+        if sig not in server.cache:
             continue
         entry = server.cache[sig]
-        plan, rows = entry.plan, dict(entry.buckets)
+        plan = entry.plan
         if entry.morsel_factor > 1:  # the fast path ran the morsel clone
             axis = P.morsel_axis(plan.root)
-            plan = P.morsel_plan(plan, entry.morsel_factor, rows=rows[axis])
-            rows[axis] = P.morsel_rows(rows[axis], entry.morsel_factor)
-        tables = {n: Table({c: jax.ShapeDtypeStruct((rows[n],), d)
-                            for c, d in cols.items()})
-                  for n, cols in rec["inputs"].items()}
-        counts = {n: jax.ShapeDtypeStruct((), jnp.int32) for n in tables}
-        texts[sig] = plan.compiled_bucketed.lower(tables, counts).compile().as_text()
+            plan = P.morsel_plan(plan, entry.morsel_factor,
+                                 rows=entry.buckets[axis])
+        texts[sig] = "\n".join(prog.compiled.as_text()
+                               for prog in plan.compiled_bucketed.values())
     return texts
 
 

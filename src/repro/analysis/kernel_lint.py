@@ -160,7 +160,8 @@ def lint_pallas_eqn(eqn, *, name: str, backend: str = DEFAULT_BACKEND,
 
 
 def _kernel_name(eqn) -> str:
-    """The kernel body's function name (`_hist_kernel`), or ''."""
+    """The kernel's name: its `pallas_call(name=...)` (`histogram`), else
+    its body's function name, or ''."""
     info = getattr(eqn.params["jaxpr"], "debug_info", None)
     return str(getattr(info, "func_src_info", "") or "").split(" ")[0]
 
@@ -171,8 +172,8 @@ def lint_fn(fn, *args, name: str | None = None,
             **kwargs) -> list[KernelLintReport]:
     """Trace `fn(*args, **kwargs)` and lint every pallas_call inside.
     `allow_output_revisit` is True for every kernel, or the names of the
-    kernel bodies that revisit output blocks by design; the others stay
-    checked."""
+    kernels (`_kernel_name`) that revisit output blocks by design; the
+    others stay checked."""
     # close over the args: static ints (num_bins, tile sizes) must reach
     # the kernel wrapper as Python values, not tracers
     closed = jax.make_jaxpr(lambda: fn(*args, **kwargs))()
@@ -204,11 +205,12 @@ def production_kernel_specs():
     """(name, thunk, revisiting kernels) for every production kernel.
     Thunks build (fn, args, kwargs) at call time so jax only initializes
     when the sweep runs. The histogram kernels declare output revisiting:
-    `_hist_kernel` accumulates its single output block across the
-    (sequential) TPU grid, and `_block_hist_kernel` (under block_histograms
-    and partition_ranks) shares one (bins, 128) output block between 128
+    `histogram` accumulates its single output block across the
+    (sequential) TPU grid, and `block_histograms` (also run by
+    partition_ranks) shares one (bins, 128) output block between 128
     consecutive grid steps, each writing its own lane column. Every other
-    kernel, partition_ranks' `_rank_kernel` among them, is checked."""
+    kernel, partition_ranks' own `partition_ranks` kernel among them, is
+    checked."""
     import jax.numpy as jnp
 
     from repro.kernels.gather import gather_windowed_pallas
@@ -243,13 +245,13 @@ def production_kernel_specs():
 
     return [
         ("histogram", lambda: (histogram_pallas, (digits(), 16), {}),
-         ("_hist_kernel",)),
+         ("histogram",)),
         ("block_histograms",
          lambda: (block_histograms_pallas, (digits(), 16), {}),
-         ("_block_hist_kernel",)),
+         ("block_histograms",)),
         ("partition_ranks",
          lambda: (partition_ranks_pallas, (digits(), 16), {}),
-         ("_block_hist_kernel",)),
+         ("block_histograms",)),
         ("segsum_partials",
          lambda: (segsum_partials_pallas,
                   (i32(np.sort(np.arange(1024) % 64)),

@@ -90,27 +90,31 @@ def groupby_sort(
     Returns (Table(key + agg columns), valid_count)."""
     table = _nonempty(table, key)  # zero rows -> one all-sentinel row
     keys = table[key]
-    sk, perm = prim.plan_sort_permutation(keys)
-    boundary = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]])
-    boundary &= sk != KEY_SENTINEL
-    valid_row = sk != KEY_SENTINEL
-    gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1  # dense, sorted group ids
-    n_found = gid[-1] + 1
-    gid = jnp.where(valid_row, gid, num_groups)
-    gid_cap = jnp.where(gid < num_groups, gid, num_groups)  # overflow -> dropped
+    with prim.phase("partition"):
+        sk, perm = prim.plan_sort_permutation(keys)
+    with prim.phase("aggregate"):
+        boundary = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]])
+        boundary &= sk != KEY_SENTINEL
+        valid_row = sk != KEY_SENTINEL
+        gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1  # dense, sorted group ids
+        n_found = gid[-1] + 1
+        gid = jnp.where(valid_row, gid, num_groups)
+        gid_cap = jnp.where(gid < num_groups, gid, num_groups)  # overflow -> dropped
 
-    out_keys = jnp.full((num_groups + 1,), KEY_SENTINEL, keys.dtype)
-    out_keys = out_keys.at[gid_cap].set(jnp.where(valid_row, sk, KEY_SENTINEL), mode="drop")
-    counts = jax.ops.segment_sum(
-        valid_row.astype(jnp.int32), gid_cap, num_segments=num_groups + 1
-    )
+        out_keys = jnp.full((num_groups + 1,), KEY_SENTINEL, keys.dtype)
+        out_keys = out_keys.at[gid_cap].set(jnp.where(valid_row, sk, KEY_SENTINEL), mode="drop")
+        counts = jax.ops.segment_sum(
+            valid_row.astype(jnp.int32), gid_cap, num_segments=num_groups + 1
+        )
 
     cols = {key: out_keys[:num_groups]}
     for col, op in aggs.items():
-        tv = prim.apply_permutation(perm, table[col])  # one gather per column
-        acc = _seg_reduce(op, jnp.where(valid_row, tv, 0) if op in ("sum", "mean") else tv,
-                          gid_cap, num_groups + 1)
-        cols[f"{col}_{op}"] = _finalize(op, acc, counts)[:num_groups]
+        with prim.phase("materialize"):
+            tv = prim.apply_permutation(perm, table[col])  # one gather per column
+        with prim.phase("aggregate"):
+            acc = _seg_reduce(op, jnp.where(valid_row, tv, 0) if op in ("sum", "mean") else tv,
+                              gid_cap, num_groups + 1)
+            cols[f"{col}_{op}"] = _finalize(op, acc, counts)[:num_groups]
     count = jnp.minimum(n_found, num_groups)
     return Table(cols), count
 
@@ -147,36 +151,40 @@ def _tile_partials(keys, cols_ops, block):
     distinct keys once — heavy hitters collapse block-fold per pass."""
     n = keys.shape[0]
     n_pad = -n % block
-    kp = jnp.pad(keys, (0, n_pad), constant_values=KEY_SENTINEL).reshape(-1, block)
-    ks, order, valid, bnd, lgid = _block_local_groups(kp)
-    oh = jax.nn.one_hot(lgid, block, dtype=jnp.float32)  # (T, block, block)
+    with prim.phase("partition"):
+        kp = jnp.pad(keys, (0, n_pad), constant_values=KEY_SENTINEL).reshape(-1, block)
+        ks, order, valid, bnd, lgid = _block_local_groups(kp)
+    with prim.phase("aggregate"):
+        oh = jax.nn.one_hot(lgid, block, dtype=jnp.float32)  # (T, block, block)
 
-    pcounts = jnp.einsum("tbg->tg", oh)
-    # group g's key: scatter run-head keys into slot g (run heads are unique per tile)
-    T = ks.shape[0]
-    pkeys = (
-        jnp.full((T, block + 1), KEY_SENTINEL, keys.dtype)
-        .at[jnp.arange(T)[:, None], jnp.where(bnd, lgid, block)]
-        .set(ks, mode="drop")[:, :block]
-    )
+        pcounts = jnp.einsum("tbg->tg", oh)
+        # group g's key: scatter run-head keys into slot g (run heads are unique per tile)
+        T = ks.shape[0]
+        pkeys = (
+            jnp.full((T, block + 1), KEY_SENTINEL, keys.dtype)
+            .at[jnp.arange(T)[:, None], jnp.where(bnd, lgid, block)]
+            .set(ks, mode="drop")[:, :block]
+        )
 
     partials = {}
     for name, (vals, pop) in cols_ops.items():
-        vp = jnp.pad(vals, (0, n_pad)).reshape(-1, block)
-        vs = jnp.take_along_axis(vp, order, axis=1).astype(jnp.float32)
-        if pop == "sum":  # HIGHEST: a TPU's default f32 matmul rounds to bf16
-            acc = jnp.einsum("tb,tbg->tg", jnp.where(valid, vs, 0.0), oh,
-                             precision=jax.lax.Precision.HIGHEST)
-        elif pop == "count":
-            acc = pcounts
-        elif pop in ("min", "max"):
-            fill = jnp.float32(jnp.finfo(jnp.float32).max if pop == "min"
-                               else jnp.finfo(jnp.float32).min)
-            masked = jnp.where(oh > 0, vs[:, :, None], fill)
-            acc = masked.min(axis=1) if pop == "min" else masked.max(axis=1)
-        else:
-            raise ValueError(pop)
-        partials[name] = acc.reshape(-1)
+        with prim.phase("materialize"):
+            vp = jnp.pad(vals, (0, n_pad)).reshape(-1, block)
+            vs = jnp.take_along_axis(vp, order, axis=1).astype(jnp.float32)
+        with prim.phase("aggregate"):
+            if pop == "sum":  # HIGHEST: a TPU's default f32 matmul rounds to bf16
+                acc = jnp.einsum("tb,tbg->tg", jnp.where(valid, vs, 0.0), oh,
+                                 precision=jax.lax.Precision.HIGHEST)
+            elif pop == "count":
+                acc = pcounts
+            elif pop in ("min", "max"):
+                fill = jnp.float32(jnp.finfo(jnp.float32).max if pop == "min"
+                                   else jnp.finfo(jnp.float32).min)
+                masked = jnp.where(oh > 0, vs[:, :, None], fill)
+                acc = masked.min(axis=1) if pop == "min" else masked.max(axis=1)
+            else:
+                raise ValueError(pop)
+            partials[name] = acc.reshape(-1)
     return pkeys.reshape(-1), pcounts.reshape(-1), partials
 
 
@@ -206,37 +214,40 @@ def groupby_partition_hash(
     pkeys, pcounts, partials = _tile_partials(keys, cols_ops, block)
 
     # Phase 2: sorted combine over partials (sum of sums / min of mins / ...).
-    sk, scnt, *svals = prim.sort_pairs(pkeys, pcounts, *partials.values())
-    valid_row = sk != KEY_SENTINEL
-    boundary = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]]) & valid_row
-    gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    n_found = gid[-1] + 1
-    gid = jnp.where(valid_row & (gid < num_groups), gid, num_groups)
+    with prim.phase("partition"):
+        sk, scnt, *svals = prim.sort_pairs(pkeys, pcounts, *partials.values())
+    with prim.phase("aggregate"):
+        valid_row = sk != KEY_SENTINEL
+        boundary = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]]) & valid_row
+        gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+        n_found = gid[-1] + 1
+        gid = jnp.where(valid_row & (gid < num_groups), gid, num_groups)
 
-    out_keys = jnp.full((num_groups + 1,), KEY_SENTINEL, keys.dtype)
-    out_keys = out_keys.at[gid].set(jnp.where(valid_row, sk, KEY_SENTINEL), mode="drop")
-    counts = jax.ops.segment_sum(jnp.where(valid_row, scnt, 0.0), gid, num_segments=num_groups + 1)
+        out_keys = jnp.full((num_groups + 1,), KEY_SENTINEL, keys.dtype)
+        out_keys = out_keys.at[gid].set(jnp.where(valid_row, sk, KEY_SENTINEL), mode="drop")
+        counts = jax.ops.segment_sum(jnp.where(valid_row, scnt, 0.0), gid,
+                                     num_segments=num_groups + 1)
 
-    out = {key: out_keys[:num_groups]}
-    for (name, (_, pop)), sv in zip(cols_ops.items(), svals):
-        _, cop = _PARTIAL[{"sum": "sum", "count": "count", "min": "min", "max": "max"}[pop]]
-        if cop == "sum":
-            acc = jax.ops.segment_sum(jnp.where(valid_row, sv, 0.0), gid,
-                                      num_segments=num_groups + 1)
-        elif cop == "min":
-            acc = jax.ops.segment_min(jnp.where(valid_row, sv, jnp.finfo(jnp.float32).max),
-                                      gid, num_segments=num_groups + 1)
-        else:
-            acc = jax.ops.segment_max(jnp.where(valid_row, sv, jnp.finfo(jnp.float32).min),
-                                      gid, num_segments=num_groups + 1)
-        out[name] = acc[:num_groups]
-    # finalize means / counts dtype
-    for col, op in aggs.items():
-        name = f"{col}_{op}"
-        if op == "mean":
-            out[name] = out[name] / jnp.maximum(counts[:num_groups], 1.0)
-        if op == "count":
-            out[name] = out[name].astype(jnp.int32)
+        out = {key: out_keys[:num_groups]}
+        for (name, (_, pop)), sv in zip(cols_ops.items(), svals):
+            _, cop = _PARTIAL[{"sum": "sum", "count": "count", "min": "min", "max": "max"}[pop]]
+            if cop == "sum":
+                acc = jax.ops.segment_sum(jnp.where(valid_row, sv, 0.0), gid,
+                                          num_segments=num_groups + 1)
+            elif cop == "min":
+                acc = jax.ops.segment_min(jnp.where(valid_row, sv, jnp.finfo(jnp.float32).max),
+                                          gid, num_segments=num_groups + 1)
+            else:
+                acc = jax.ops.segment_max(jnp.where(valid_row, sv, jnp.finfo(jnp.float32).min),
+                                          gid, num_segments=num_groups + 1)
+            out[name] = acc[:num_groups]
+        # finalize means / counts dtype
+        for col, op in aggs.items():
+            name = f"{col}_{op}"
+            if op == "mean":
+                out[name] = out[name] / jnp.maximum(counts[:num_groups], 1.0)
+            if op == "count":
+                out[name] = out[name].astype(jnp.int32)
     count = jnp.minimum(n_found, num_groups)
     return Table(out), count
 
@@ -354,100 +365,104 @@ def groupby_partition(
     n = keys.shape[0]
     p_bits, row_block = _partition_layout(n, row_block, partition_bits)
     P = 1 << p_bits
-    digits = _partition_digits(keys, p_bits)
-    # One-permutation plan over P+1 partitions (the extra one swallows
-    # sentinel padding and is never materialized). The key column comes back
-    # already partitioned (Algorithm 1's key-rides-along idiom).
-    perm, (keys_part,), offsets, sizes = prim.plan_partition_permutation(
-        digits, P + 1, carry=(keys,))
+    with prim.phase("partition"):
+        digits = _partition_digits(keys, p_bits)
+        # One-permutation plan over P+1 partitions (the extra one swallows
+        # sentinel padding and is never materialized). The key column comes back
+        # already partitioned (Algorithm 1's key-rides-along idiom).
+        perm, (keys_part,), offsets, sizes = prim.plan_partition_permutation(
+            digits, P + 1, carry=(keys,))
 
-    # Blocked VMEM layout of the P valid partitions: position (p, i) holds
-    # the i-th row of partition p. Composing the block map with the planned
-    # permutation gathers every payload column from the ORIGINAL table
-    # exactly once; the key is a clustered read of the carried column.
-    i = jnp.arange(row_block, dtype=jnp.int32)[None, :]
-    pos = offsets[:P, None] + i
-    in_part = i < jnp.minimum(sizes[:P, None], row_block)
-    pos_c = jnp.clip(pos, 0, n - 1)
-    src = jnp.take(perm, pos_c)  # (P, row_block) source rows for payloads
-    kblocks = jnp.where(in_part, jnp.take(keys_part, pos_c),
-                        jnp.asarray(KEY_SENTINEL, keys.dtype))
+        # Blocked VMEM layout of the P valid partitions: position (p, i) holds
+        # the i-th row of partition p. Composing the block map with the planned
+        # permutation gathers every payload column from the ORIGINAL table
+        # exactly once; the key is a clustered read of the carried column.
+        i = jnp.arange(row_block, dtype=jnp.int32)[None, :]
+        pos = offsets[:P, None] + i
+        in_part = i < jnp.minimum(sizes[:P, None], row_block)
+        pos_c = jnp.clip(pos, 0, n - 1)
+        src = jnp.take(perm, pos_c)  # (P, row_block) source rows for payloads
+        kblocks = jnp.where(in_part, jnp.take(keys_part, pos_c),
+                            jnp.asarray(KEY_SENTINEL, keys.dtype))
 
+    val_names = [c for c, op in aggs.items() if op != "count"]
+    uniq_cols = list(dict.fromkeys(val_names))
+    with prim.phase("materialize"):
+        vblocks = [jnp.take(table[c], src) for c in uniq_cols]  # col's ONE gather
     # Per-partition grouping: ONE stable block-local sort moves the key and
     # every aggregate input together (a group lives in exactly one
     # partition, so block runs are final groups). Sentinel slots sort to the
     # front of their block and are masked out of every reduction.
-    val_names = [c for c, op in aggs.items() if op != "count"]
-    uniq_cols = list(dict.fromkeys(val_names))
-    vblocks = [jnp.take(table[c], src) for c in uniq_cols]  # col's ONE gather
-    sorted_ = jax.lax.sort((kblocks,) + tuple(vblocks), num_keys=1,
-                           is_stable=True)
-    ks = sorted_[0]
-    vsorted = dict(zip(uniq_cols, sorted_[1:]))
-    n_slots = P * row_block
-    ksf = ks.reshape(-1)
-    valid = (ksf != jnp.asarray(KEY_SENTINEL, keys.dtype))
-    head = jnp.concatenate(
-        [jnp.ones((P, 1), bool), ks[:, 1:] != ks[:, :-1]], axis=1).reshape(-1)
-    bnd = head & valid
-    rid = jnp.cumsum(bnd.astype(jnp.int32)) - 1  # monotone run id per slot
-    n_found = rid[-1] + 1 if n_slots else jnp.zeros((), jnp.int32)
-    count = jnp.minimum(n_found, num_groups)
+    with prim.phase("partition"):
+        sorted_ = jax.lax.sort((kblocks,) + tuple(vblocks), num_keys=1,
+                               is_stable=True)
+    with prim.phase("aggregate"):
+        ks = sorted_[0]
+        vsorted = dict(zip(uniq_cols, sorted_[1:]))
+        n_slots = P * row_block
+        ksf = ks.reshape(-1)
+        valid = (ksf != jnp.asarray(KEY_SENTINEL, keys.dtype))
+        head = jnp.concatenate(
+            [jnp.ones((P, 1), bool), ks[:, 1:] != ks[:, :-1]], axis=1).reshape(-1)
+        bnd = head & valid
+        rid = jnp.cumsum(bnd.astype(jnp.int32)) - 1  # monotone run id per slot
+        n_found = rid[-1] + 1 if n_slots else jnp.zeros((), jnp.int32)
+        count = jnp.minimum(n_found, num_groups)
 
-    # Dense compaction without a scatter: rid is sorted, so the r-th run's
-    # first slot is a binary search; run r spans [starts[r], starts[r+1]).
-    r_iota = jnp.arange(num_groups + 1, dtype=jnp.int32)
-    starts = jnp.searchsorted(rid, r_iota, side="left").astype(jnp.int32)
-    starts_c = jnp.clip(starts[:num_groups], 0, max(n_slots - 1, 0))
-    present = jnp.arange(num_groups, dtype=jnp.int32) < count
-    out_keys = jnp.where(present, jnp.take(ksf, starts_c),
-                         jnp.asarray(KEY_SENTINEL, keys.dtype))
+        # Dense compaction without a scatter: rid is sorted, so the r-th run's
+        # first slot is a binary search; run r spans [starts[r], starts[r+1]).
+        r_iota = jnp.arange(num_groups + 1, dtype=jnp.int32)
+        starts = jnp.searchsorted(rid, r_iota, side="left").astype(jnp.int32)
+        starts_c = jnp.clip(starts[:num_groups], 0, max(n_slots - 1, 0))
+        present = jnp.arange(num_groups, dtype=jnp.int32) < count
+        out_keys = jnp.where(present, jnp.take(ksf, starts_c),
+                             jnp.asarray(KEY_SENTINEL, keys.dtype))
 
-    def run_total(per_slot):
-        """Count over each run via an exclusive cumsum differenced at run
-        boundaries — int32 is exact however long the prefix, never a
-        scatter."""
-        ecs = jnp.concatenate([jnp.zeros((1,), per_slot.dtype),
-                               jnp.cumsum(per_slot)])
-        return jnp.take(ecs, starts[1:]) - jnp.take(ecs, starts[:num_groups])
+        def run_total(per_slot):
+            """Count over each run via an exclusive cumsum differenced at run
+            boundaries — int32 is exact however long the prefix, never a
+            scatter."""
+            ecs = jnp.concatenate([jnp.zeros((1,), per_slot.dtype),
+                                   jnp.cumsum(per_slot)])
+            return jnp.take(ecs, starts[1:]) - jnp.take(ecs, starts[:num_groups])
 
-    # Float run sums use BLOCK-LOCAL exclusive cumsums instead: a run never
-    # spans blocks (valid rows are a block's sorted suffix), so the prefix a
-    # difference cancels is bounded by one block's magnitude — the rounding
-    # error of a global n-slot prefix would grow with the whole relation.
-    s_flat = starts[:num_groups]
-    e_flat = starts[1:]
-    row_s = jnp.minimum(s_flat // row_block, P - 1)
-    col_s = s_flat - (s_flat // row_block) * row_block
-    col_e = jnp.where(e_flat // row_block == s_flat // row_block,
-                      e_flat - (e_flat // row_block) * row_block, row_block)
+        # Float run sums use BLOCK-LOCAL exclusive cumsums instead: a run never
+        # spans blocks (valid rows are a block's sorted suffix), so the prefix a
+        # difference cancels is bounded by one block's magnitude — the rounding
+        # error of a global n-slot prefix would grow with the whole relation.
+        s_flat = starts[:num_groups]
+        e_flat = starts[1:]
+        row_s = jnp.minimum(s_flat // row_block, P - 1)
+        col_s = s_flat - (s_flat // row_block) * row_block
+        col_e = jnp.where(e_flat // row_block == s_flat // row_block,
+                          e_flat - (e_flat // row_block) * row_block, row_block)
 
-    def run_block_total(masked2d):
-        ecs = jnp.concatenate(
-            [jnp.zeros((P, 1), masked2d.dtype), jnp.cumsum(masked2d, axis=1)],
-            axis=1).reshape(-1)  # (P * (row_block+1),)
-        hi = jnp.take(ecs, row_s * (row_block + 1) + col_e)
-        lo = jnp.take(ecs, row_s * (row_block + 1) + col_s)
-        return jnp.where(present, hi - lo, jnp.zeros((), masked2d.dtype))
+        def run_block_total(masked2d):
+            ecs = jnp.concatenate(
+                [jnp.zeros((P, 1), masked2d.dtype), jnp.cumsum(masked2d, axis=1)],
+                axis=1).reshape(-1)  # (P * (row_block+1),)
+            hi = jnp.take(ecs, row_s * (row_block + 1) + col_e)
+            lo = jnp.take(ecs, row_s * (row_block + 1) + col_s)
+            return jnp.where(present, hi - lo, jnp.zeros((), masked2d.dtype))
 
-    valid2d = valid.reshape(P, row_block)
-    counts = run_total(valid.astype(jnp.int32))
-    cols = {key: out_keys}
-    for col, op in aggs.items():
-        if op == "count":
-            cols[f"{col}_{op}"] = counts
-            continue
-        vs = vsorted[col].reshape(-1)
-        if op in ("sum", "mean"):
-            acc = run_block_total(
-                jnp.where(valid2d, vsorted[col], jnp.zeros((), vs.dtype)))
-        else:  # min/max: not expressible as a cumsum difference
-            seg = jnp.where(valid & (rid < num_groups), rid, num_groups)
-            fill = (jnp.finfo if jnp.issubdtype(vs.dtype, jnp.floating)
-                    else jnp.iinfo)(vs.dtype)
-            masked = jnp.where(valid, vs, fill.max if op == "min" else fill.min)
-            acc = _seg_reduce(op, masked, seg, num_groups + 1)[:num_groups]
-        cols[f"{col}_{op}"] = _finalize(op, acc, counts)
+        valid2d = valid.reshape(P, row_block)
+        counts = run_total(valid.astype(jnp.int32))
+        cols = {key: out_keys}
+        for col, op in aggs.items():
+            if op == "count":
+                cols[f"{col}_{op}"] = counts
+                continue
+            vs = vsorted[col].reshape(-1)
+            if op in ("sum", "mean"):
+                acc = run_block_total(
+                    jnp.where(valid2d, vsorted[col], jnp.zeros((), vs.dtype)))
+            else:  # min/max: not expressible as a cumsum difference
+                seg = jnp.where(valid & (rid < num_groups), rid, num_groups)
+                fill = (jnp.finfo if jnp.issubdtype(vs.dtype, jnp.floating)
+                        else jnp.iinfo)(vs.dtype)
+                masked = jnp.where(valid, vs, fill.max if op == "min" else fill.min)
+                acc = _seg_reduce(op, masked, seg, num_groups + 1)[:num_groups]
+            cols[f"{col}_{op}"] = _finalize(op, acc, counts)
     return Table(cols), count
 
 
@@ -572,25 +587,26 @@ def groupby_scatter(
         raise TypeError(
             f"scatter group-by needs integer keys, got {keys.dtype}; "
             "float keys would be silently floored into merged groups")
-    in_domain = (keys >= 0) & (keys < num_groups)
-    gid = jnp.where(in_domain, keys, num_groups).astype(jnp.int32)
-    counts = jax.ops.segment_sum(
-        in_domain.astype(jnp.int32), gid, num_segments=num_groups + 1
-    )[:num_groups]
-    present = counts > 0
-    out = {key: jnp.arange(num_groups, dtype=keys.dtype)}
-    for col, op in aggs.items():
-        vals = table[col]
-        if op in ("sum", "mean"):
-            vals = jnp.where(in_domain, vals, 0)
-        acc = _seg_reduce(op, vals, gid, num_groups + 1)[:num_groups]
-        out[f"{col}_{op}"] = _finalize(op, acc, counts)
-    names = list(out)
-    compacted, n_present = prim.compact(present, [out[n] for n in names],
-                                        num_groups)
-    out = dict(zip(names, compacted))
-    out[key] = jnp.where(jnp.arange(num_groups) < n_present, out[key],
-                         jnp.asarray(KEY_SENTINEL, keys.dtype))
+    with prim.phase("aggregate"):
+        in_domain = (keys >= 0) & (keys < num_groups)
+        gid = jnp.where(in_domain, keys, num_groups).astype(jnp.int32)
+        counts = jax.ops.segment_sum(
+            in_domain.astype(jnp.int32), gid, num_segments=num_groups + 1
+        )[:num_groups]
+        present = counts > 0
+        out = {key: jnp.arange(num_groups, dtype=keys.dtype)}
+        for col, op in aggs.items():
+            vals = table[col]
+            if op in ("sum", "mean"):
+                vals = jnp.where(in_domain, vals, 0)
+            acc = _seg_reduce(op, vals, gid, num_groups + 1)[:num_groups]
+            out[f"{col}_{op}"] = _finalize(op, acc, counts)
+        names = list(out)
+        compacted, n_present = prim.compact(present, [out[n] for n in names],
+                                            num_groups)
+        out = dict(zip(names, compacted))
+        out[key] = jnp.where(jnp.arange(num_groups) < n_present, out[key],
+                             jnp.asarray(KEY_SENTINEL, keys.dtype))
     return Table(out), n_present
 
 
@@ -617,23 +633,28 @@ def groupby_sort_pallas(
     for op in aggs.values():
         if op not in ("sum", "mean", "count"):
             raise ValueError(f"sort_pallas supports sum/mean/count, got {op}")
-    sk, perm = prim.plan_sort_permutation(keys)
+    with prim.phase("partition"):
+        sk, perm = prim.plan_sort_permutation(keys)
     out = {}
     count = gc = None
     if any(op in ("mean", "count") for op in aggs.values()):
         # hoisted key-only count pass (shared by every mean/count column)
-        out[key], gc, count = kops.groupby_sorted_sum(
-            sk, jnp.ones(sk.shape, jnp.float32), num_groups, "pallas", tile=tile)
+        with prim.phase("aggregate"):
+            out[key], gc, count = kops.groupby_sorted_sum(
+                sk, jnp.ones(sk.shape, jnp.float32), num_groups, "pallas",
+                tile=tile)
     for col, op in aggs.items():
         if op == "count":
             out[f"{col}_{op}"] = gc.astype(jnp.int32)
             continue
-        sv = prim.apply_permutation(perm, table[col])  # one gather per column
-        gk, gs, cnt = kops.groupby_sorted_sum(sk, sv.astype(jnp.float32),
-                                              num_groups, "pallas", tile=tile)
-        if count is None:
-            out[key], count = gk, cnt
-        out[f"{col}_{op}"] = gs if op == "sum" else gs / jnp.maximum(gc, 1.0)
+        with prim.phase("materialize"):
+            sv = prim.apply_permutation(perm, table[col])  # one gather per column
+        with prim.phase("aggregate"):
+            gk, gs, cnt = kops.groupby_sorted_sum(sk, sv.astype(jnp.float32),
+                                                  num_groups, "pallas", tile=tile)
+            if count is None:
+                out[key], count = gk, cnt
+            out[f"{col}_{op}"] = gs if op == "sum" else gs / jnp.maximum(gc, 1.0)
     return Table(out), count
 
 

@@ -112,18 +112,19 @@ def phj_groupjoin(
               else choose_partition_bits(R.num_rows, build_block))
     P = 1 << p_bits
 
-    dig_r = _digits(R[key], p_bits, hash_keys)
-    dig_s = _digits(S[key], p_bits, hash_keys)
-    # P + 1 partitions: sentinel rows flood the extra one (see
-    # hash_join._digits) and never reach a build block or probe pass
-    perm_r, off_r, sz_r = prim.plan_partition_permutation(dig_r, P + 1)
-    perm_s, off_s, sz_s = prim.plan_partition_permutation(dig_s, P + 1)
-    off_r, sz_r = off_r[:P], sz_r[:P]
-    off_s, sz_s = off_s[:P], sz_s[:P]
+    with prim.phase("partition"):
+        dig_r = _digits(R[key], p_bits, hash_keys)
+        dig_s = _digits(S[key], p_bits, hash_keys)
+        # P + 1 partitions: sentinel rows flood the extra one (see
+        # hash_join._digits) and never reach a build block or probe pass
+        perm_r, off_r, sz_r = prim.plan_partition_permutation(dig_r, P + 1)
+        perm_s, off_s, sz_s = prim.plan_partition_permutation(dig_s, P + 1)
+        off_r, sz_r = off_r[:P], sz_r[:P]
+        off_s, sz_s = off_s[:P], sz_s[:P]
 
-    kr = prim.apply_permutation(perm_r, R[key])
-    ks, dig_s_part = prim.apply_permutation(perm_s, S[key], dig_s)
-    bkeys, _, _ = build_blocks(kr, off_r, sz_r, build_block)
+        kr = prim.apply_permutation(perm_r, R[key])
+        ks, dig_s_part = prim.apply_permutation(perm_s, S[key], dig_s)
+        bkeys, _, _ = build_blocks(kr, off_r, sz_r, build_block)
 
     # Probe-side columns reach partitioned order by the one-permutation
     # layer's lazy transform: exactly one planned-permutation gather per
@@ -136,34 +137,37 @@ def phj_groupjoin(
             probe_part[col] = prim.apply_permutation(perm_s, S[col])
         return probe_part[col]
 
-    gk = probe_col(group_key)
+    with prim.phase("partition"):  # the group key is a key column
+        gk = probe_col(group_key)
 
     if probe_impl == "pallas":
         return _groupjoin_pallas(R, S, key, aggs, num_groups, bkeys, off_r,
                                  sz_r, perm_r, probe_col, gk, off_s, sz_s,
                                  group_key)
 
-    vid_r, matched = probe_pk_fk(bkeys, off_r, ks, dig_s_part, probe_chunk)
-    gk_masked = jnp.where(matched, gk, jnp.asarray(KEY_SENTINEL, gk.dtype))
+    with prim.phase("probe"):
+        vid_r, matched = probe_pk_fk(bkeys, off_r, ks, dig_s_part, probe_chunk)
+        gk_masked = jnp.where(matched, gk, jnp.asarray(KEY_SENTINEL, gk.dtype))
 
     # Per-row aggregate inputs in partitioned probe order — the rows the
     # accumulator consumes directly; the joined row is never assembled.
     cols = {group_key: gk_masked}
-    for col, op in aggs.items():
-        if col in cols:
-            continue  # aggregating the group key: reuse the masked column
-        if op == "count":
-            # counts ignore values on every strategy; skip any fetch
-            cols[col] = jnp.zeros(ks.shape, jnp.int32)
-        elif col in S.column_names:
-            cols[col] = probe_col(col)  # the column's ONE lazy-transform gather
-        else:
-            # build-side input, GFTR pattern: transform once (one n_build
-            # permutation gather), then ONE clustered probe-length gather
-            # through the matched virtual IDs (clustered within
-            # co-partitions — the same access shape as phj_join's ID_R)
-            tr = prim.apply_permutation(perm_r, R[col])
-            cols[col] = prim.gather(tr, jnp.where(matched, vid_r, -1), fill=0)
+    with prim.phase("materialize"):
+        for col, op in aggs.items():
+            if col in cols:
+                continue  # aggregating the group key: reuse the masked column
+            if op == "count":
+                # counts ignore values on every strategy; skip any fetch
+                cols[col] = jnp.zeros(ks.shape, jnp.int32)
+            elif col in S.column_names:
+                cols[col] = probe_col(col)  # the column's ONE lazy-transform gather
+            else:
+                # build-side input, GFTR pattern: transform once (one n_build
+                # permutation gather), then ONE clustered probe-length gather
+                # through the matched virtual IDs (clustered within
+                # co-partitions — the same access shape as phj_join's ID_R)
+                tr = prim.apply_permutation(perm_r, R[col])
+                cols[col] = prim.gather(tr, jnp.where(matched, vid_r, -1), fill=0)
 
     return group_aggregate(Table(cols), key=group_key, aggs=aggs,
                            num_groups=num_groups, strategy=agg_strategy,
@@ -192,28 +196,35 @@ def _groupjoin_pallas(R, S, key, aggs, num_groups, bkeys, off_r, sz_r, perm_r,
     ks = probe_col(key)
     sum_cols = [(col, op) for col, op in aggs.items() if op != "count"]
     col_sides, pv_cols, bv_cols = [], [], []
-    for col, _ in sum_cols:
-        if col in S.column_names:
-            col_sides.append(("probe", len(pv_cols)))
-            pv_cols.append(probe_col(col).astype(jnp.float32))
-        else:
-            vr_part = prim.apply_permutation(perm_r, R[col])
-            col_sides.append(("build", len(bv_cols)))
-            bv_cols.append(_value_blocks(vr_part, off_r, sz_r, bkeys.shape[1]))
-    gkeys, sums, gcounts, count = kops.groupjoin_probe_agg(
-        bkeys, jnp.stack(bv_cols, axis=1) if bv_cols else None, off_r,
-        ks, gk, jnp.stack(pv_cols) if pv_cols else None, off_s, sz_s,
-        num_groups, col_sides=tuple(col_sides), impl="pallas")
+    with prim.phase("materialize"):
+        for col, _ in sum_cols:
+            if col in S.column_names:
+                col_sides.append(("probe", len(pv_cols)))
+                pv_cols.append(probe_col(col).astype(jnp.float32))
+            else:
+                vr_part = prim.apply_permutation(perm_r, R[col])
+                col_sides.append(("build", len(bv_cols)))
+                bv_cols.append(_value_blocks(vr_part, off_r, sz_r,
+                                             bkeys.shape[1]))
+        bv = jnp.stack(bv_cols, axis=1) if bv_cols else None
+        pv = jnp.stack(pv_cols) if pv_cols else None
+    # one fused pass finds the matches and accumulates them: its time counts
+    # as match finding
+    with prim.phase("probe"):
+        gkeys, sums, gcounts, count = kops.groupjoin_probe_agg(
+            bkeys, bv, off_r, ks, gk, pv, off_s, sz_s, num_groups,
+            col_sides=tuple(col_sides), impl="pallas")
 
     out: dict[str, jax.Array] = {}
     for (col, op), s in zip(sum_cols, sums):
         out[f"{col}_{op}"] = s
-    for col, op in aggs.items():
-        if op == "count":
-            out[f"{col}_{op}"] = gcounts.astype(jnp.int32)
-        elif op == "mean":
-            out[f"{col}_{op}"] = out[f"{col}_{op}"] / jnp.maximum(
-                gcounts.astype(jnp.float32), 1.0)
+    with prim.phase("aggregate"):
+        for col, op in aggs.items():
+            if op == "count":
+                out[f"{col}_{op}"] = gcounts.astype(jnp.int32)
+            elif op == "mean":
+                out[f"{col}_{op}"] = out[f"{col}_{op}"] / jnp.maximum(
+                    gcounts.astype(jnp.float32), 1.0)
     return Table({group_key: gkeys, **out}), count
 
 

@@ -215,71 +215,77 @@ def phj_join(
     )
     P = 1 << p_bits
 
-    dig_r = _digits(R[key], p_bits, hash_keys)
-    dig_s = _digits(S[key], p_bits, hash_keys)
-    # One-permutation transform plan (multi-pass radix semantics; determinism
-    # by construction — §4.3's requirement): the partition is planned once
-    # per side and every column it touches costs exactly one gather. P + 1
-    # partitions: the extra one swallows sentinel rows (see _digits) and
-    # never gets a build block or a probe pass.
-    perm_r, off_r, sz_r = prim.plan_partition_permutation(dig_r, P + 1)
-    perm_s, off_s, sz_s = prim.plan_partition_permutation(dig_s, P + 1)
+    with prim.phase("partition"):
+        dig_r = _digits(R[key], p_bits, hash_keys)
+        dig_s = _digits(S[key], p_bits, hash_keys)
+        # One-permutation transform plan (multi-pass radix semantics;
+        # determinism by construction — §4.3's requirement): the partition
+        # is planned once per side and every column it touches costs exactly
+        # one gather. P + 1 partitions: the extra one swallows sentinel rows
+        # (see _digits) and never gets a build block or a probe pass.
+        perm_r, off_r, sz_r = prim.plan_partition_permutation(dig_r, P + 1)
+        perm_s, off_s, sz_s = prim.plan_partition_permutation(dig_s, P + 1)
 
-    kr = prim.apply_permutation(perm_r, R[key])
-    ks, dig_s_part = prim.apply_permutation(perm_s, S[key], dig_s)
+        kr = prim.apply_permutation(perm_r, R[key])
+        ks, dig_s_part = prim.apply_permutation(perm_s, S[key], dig_s)
 
-    bkeys, _, overflow = build_blocks(kr, off_r[:P], sz_r[:P], build_block)
+        bkeys, _, overflow = build_blocks(kr, off_r[:P], sz_r[:P], build_block)
 
-    if mode == "pk_fk":
-        if probe_impl == "pallas":
-            from repro.kernels import ops as _kops
+    with prim.phase("probe"):
+        if mode == "pk_fk":
+            if probe_impl == "pallas":
+                from repro.kernels import ops as _kops
 
-            vid_r, matched = _kops.hash_probe(bkeys, off_r[:P], ks,
-                                              off_s[:P], sz_s[:P], "pallas")
+                vid_r, matched = _kops.hash_probe(bkeys, off_r[:P], ks,
+                                                  off_s[:P], sz_s[:P], "pallas")
+            else:
+                vid_r, matched = probe_pk_fk(bkeys, off_r, ks, dig_s_part,
+                                             probe_chunk)
+            vid_s = jnp.arange(ks.shape[0], dtype=jnp.int32)
+            (keys_o, vr, vs), count = prim.compact(
+                matched, [ks, vid_r, vid_s], out_size, fill=KEY_SENTINEL
+            )
+            valid = jnp.arange(out_size) < count
         else:
-            vid_r, matched = probe_pk_fk(bkeys, off_r, ks, dig_s_part, probe_chunk)
-        vid_s = jnp.arange(ks.shape[0], dtype=jnp.int32)
-        (keys_o, vr, vs), count = prim.compact(
-            matched, [ks, vid_r, vid_s], out_size, fill=KEY_SENTINEL
-        )
-        valid = jnp.arange(out_size) < count
-    else:
-        counts = probe_counts(bkeys, ks, dig_s_part, probe_chunk)
-        rows, ranks, valid, total = prim.expand_offsets(counts, out_size)
-        vr = probe_kth_match(bkeys, off_r, ks, dig_s_part, rows, ranks, probe_chunk)
-        vs = rows
-        keys_o = jnp.where(valid, jnp.take(ks, vs), KEY_SENTINEL)
-        count = jnp.minimum(total, out_size)
+            counts = probe_counts(bkeys, ks, dig_s_part, probe_chunk)
+            rows, ranks, valid, total = prim.expand_offsets(counts, out_size)
+            vr = probe_kth_match(bkeys, off_r, ks, dig_s_part, rows, ranks,
+                                 probe_chunk)
+            vs = rows
+            keys_o = jnp.where(valid, jnp.take(ks, vs), KEY_SENTINEL)
+            count = jnp.minimum(total, out_size)
 
-    ID_R = jnp.where(valid, vr, -1)
-    ID_S = jnp.where(valid, vs, -1)
+    with prim.phase("materialize"):
+        ID_R = jnp.where(valid, vr, -1)
+        ID_S = jnp.where(valid, vs, -1)
 
-    cols = {key: keys_o}
-    if pattern == "gfur":
-        # UM: translate to physical IDs of the untransformed inputs.
-        pid_r = jnp.where(valid, jnp.take(perm_r, jnp.clip(vr, 0, R.num_rows - 1)), -1)
-        pid_s = jnp.where(valid, jnp.take(perm_s, jnp.clip(vs, 0, S.num_rows - 1)), -1)
-        for n in r_pay:
-            cols[n] = prim.gather(R[n], pid_r, fill=0)  # unclustered
-        for n in s_pay:
-            cols[n] = prim.gather(S[n], pid_s, fill=0)  # unclustered
-    elif pattern == "gftr":
-        # OM: gather from partitioned relations. Probe-side IDs are perfectly
-        # clustered; build-side IDs are clustered within partitions (§4.3).
-        if gather_impl == "pallas":
-            from repro.kernels import ops as _kops
+        cols = {key: keys_o}
+        if pattern == "gfur":
+            # UM: translate to physical IDs of the untransformed inputs.
+            pid_r = jnp.where(valid, jnp.take(perm_r, jnp.clip(vr, 0, R.num_rows - 1)), -1)
+            pid_s = jnp.where(valid, jnp.take(perm_s, jnp.clip(vs, 0, S.num_rows - 1)), -1)
+            for n in r_pay:
+                cols[n] = prim.gather(R[n], pid_r, fill=0)  # unclustered
+            for n in s_pay:
+                cols[n] = prim.gather(S[n], pid_s, fill=0)  # unclustered
+        elif pattern == "gftr":
+            # OM: gather from partitioned relations. Probe-side IDs are
+            # perfectly clustered; build-side IDs are clustered within
+            # partitions (§4.3).
+            if gather_impl == "pallas":
+                from repro.kernels import ops as _kops
 
-            _g = lambda src, idx: _kops.clustered_gather(src, idx, "auto")
+                _g = lambda src, idx: _kops.clustered_gather(src, idx, "auto")
+            else:
+                _g = lambda src, idx: prim.gather(src, idx, fill=0)
+            for n in r_pay:
+                tr_n = prim.apply_permutation(perm_r, R[n])  # col n's ONE gather
+                cols[n] = _g(tr_n, ID_R)
+            for n in s_pay:
+                ts_n = prim.apply_permutation(perm_s, S[n])
+                cols[n] = _g(ts_n, ID_S)
         else:
-            _g = lambda src, idx: prim.gather(src, idx, fill=0)
-        for n in r_pay:
-            tr_n = prim.apply_permutation(perm_r, R[n])  # col n's ONE gather
-            cols[n] = _g(tr_n, ID_R)
-        for n in s_pay:
-            ts_n = prim.apply_permutation(perm_s, S[n])
-            cols[n] = _g(ts_n, ID_S)
-    else:
-        raise ValueError(f"unknown pattern {pattern!r}")
+            raise ValueError(f"unknown pattern {pattern!r}")
 
     return Table(cols), count
 

@@ -98,18 +98,22 @@ def nphj_join(
     if out_size is None:
         out_size = S.num_rows
     table_size = 1 << max(3, (int(R.num_rows / load_factor) - 1).bit_length())
-    slot_keys, slot_vids, _failed = build_table(R[key], table_size, max_rounds)
-    vid_r, matched = probe_table(slot_keys, slot_vids, S[key], max_rounds)
-    vid_s = jnp.arange(S.num_rows, dtype=jnp.int32)
-    (keys_o, vr, vs), count = prim.compact(
-        matched, [S[key], vid_r, vid_s], out_size, fill=KEY_SENTINEL
-    )
-    valid = jnp.arange(out_size) < count
-    cols = {key: keys_o}
-    for n in R.column_names:
-        if n != key:
-            cols[n] = prim.gather(R[n], jnp.where(valid, vr, -1), fill=0)
-    for n in S.column_names:
-        if n != key:
-            cols[n] = prim.gather(S[n], jnp.where(valid, vs, -1), fill=0)
+    with prim.phase("partition"):  # the build side's key structure
+        slot_keys, slot_vids, _failed = build_table(R[key], table_size,
+                                                    max_rounds)
+    with prim.phase("probe"):
+        vid_r, matched = probe_table(slot_keys, slot_vids, S[key], max_rounds)
+        vid_s = jnp.arange(S.num_rows, dtype=jnp.int32)
+        (keys_o, vr, vs), count = prim.compact(
+            matched, [S[key], vid_r, vid_s], out_size, fill=KEY_SENTINEL
+        )
+        valid = jnp.arange(out_size) < count
+    with prim.phase("materialize"):
+        cols = {key: keys_o}
+        for n in R.column_names:
+            if n != key:
+                cols[n] = prim.gather(R[n], jnp.where(valid, vr, -1), fill=0)
+        for n in S.column_names:
+            if n != key:
+                cols[n] = prim.gather(S[n], jnp.where(valid, vs, -1), fill=0)
     return Table(cols), count

@@ -27,6 +27,30 @@ import jax.numpy as jnp
 
 RADIX_BITS_PER_PASS = 8  # paper §2.3: Ampere RADIX-PARTITION does max 8 bits
 
+# The phases of an operator's device work (the paper's question: how much of
+# a join is partitioning, match finding and materialization). Every piece of
+# work inside a core operator sits in exactly one of them:
+#   partition    key-side transforms: digits, histograms, ranks, the
+#                permutation, key blocks and sorts of keys (a sort that
+#                carries payloads along with the keys counts here)
+#   probe        match finding: hash probe, merge join, match counts and the
+#                compaction of the match list
+#   materialize  every move of a non-key payload column: its trip through a
+#                partition permutation (GFTR's transform) and its gather into
+#                the output (GFUR) — the paper's random-access cost
+#   aggregate    reduction arithmetic: run boundaries, segmented sums, block
+#                partials and combines
+PHASES = ("partition", "probe", "materialize", "aggregate")
+
+
+def phase(name: str):
+    """`jax.named_scope` of one phase (`PHASES`): it names the compiled
+    operations' `op_name` metadata, so a device trace can be split by phase.
+    Costs nothing at run time."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r}; expected one of {PHASES}")
+    return jax.named_scope(name)
+
 # Production arm for full key-sort plans. XLA's tuned sort is the deliberate
 # default (the paper's vendor SORT-PAIRS choice); 'radix' runs the same
 # kernel-backed rank passes the partition planner uses, making SMJ's GFTR

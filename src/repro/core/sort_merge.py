@@ -114,21 +114,24 @@ def _find(kr, ks, mode, out_size, find_impl="xla"):
 
 def _smj_gfur(R, S, key, r_pay, s_pay, out_size, mode, find_impl="xla"):
     # Transformation: sort only (key, physical ID) — the "narrow" transform.
-    id_r = jnp.arange(R.num_rows, dtype=jnp.int32)
-    id_s = jnp.arange(S.num_rows, dtype=jnp.int32)
-    kr, pid_r = prim.sort_pairs(R[key], id_r)
-    ks, pid_s = prim.sort_pairs(S[key], id_s)
+    with prim.phase("partition"):
+        id_r = jnp.arange(R.num_rows, dtype=jnp.int32)
+        id_s = jnp.arange(S.num_rows, dtype=jnp.int32)
+        kr, pid_r = prim.sort_pairs(R[key], id_r)
+        ks, pid_s = prim.sort_pairs(S[key], id_s)
     # Match finding (virtual ids w.r.t. sorted arrays) ...
-    keys_o, vr, vs, valid, count = _find(kr, ks, mode, out_size, find_impl)
+    with prim.phase("probe"):
+        keys_o, vr, vs, valid, count = _find(kr, ks, mode, out_size, find_impl)
     # ... translated to *physical* IDs of the untransformed relations: the
     # permutation makes them unclustered — this is GFUR's flaw (§3.3).
-    ID_R = jnp.where(valid, jnp.take(pid_r, vr), -1)
-    ID_S = jnp.where(valid, jnp.take(pid_s, vs), -1)
-    cols = {key: keys_o}
-    for n in r_pay:  # unclustered gathers from original R
-        cols[n] = prim.gather(R[n], ID_R, fill=0)
-    for n in s_pay:  # unclustered gathers from original S
-        cols[n] = prim.gather(S[n], ID_S, fill=0)
+    with prim.phase("materialize"):
+        ID_R = jnp.where(valid, jnp.take(pid_r, vr), -1)
+        ID_S = jnp.where(valid, jnp.take(pid_s, vs), -1)
+        cols = {key: keys_o}
+        for n in r_pay:  # unclustered gathers from original R
+            cols[n] = prim.gather(R[n], ID_R, fill=0)
+        for n in s_pay:  # unclustered gathers from original S
+            cols[n] = prim.gather(S[n], ID_S, fill=0)
     return Table(cols), count
 
 
@@ -136,25 +139,30 @@ def _smj_gftr(R, S, key, r_pay, s_pay, out_size, mode, find_impl="xla"):
     # Algorithm 1 with the one-permutation refinement (DESIGN.md §8): the
     # key sort is planned ONCE per relation, and every payload column —
     # first or lazy — is transformed with a single apply_permutation gather.
-    kr, perm_r = prim.plan_sort_permutation(R[key])
-    ks, perm_s = prim.plan_sort_permutation(S[key])
-    tr = {n: prim.apply_permutation(perm_r, R[n]) for n in r_pay[:1]}
-    ts = {n: prim.apply_permutation(perm_s, S[n]) for n in s_pay[:1]}
+    with prim.phase("partition"):
+        kr, perm_r = prim.plan_sort_permutation(R[key])
+        ks, perm_s = prim.plan_sort_permutation(S[key])
+    with prim.phase("materialize"):
+        tr = {n: prim.apply_permutation(perm_r, R[n]) for n in r_pay[:1]}
+        ts = {n: prim.apply_permutation(perm_s, S[n]) for n in s_pay[:1]}
     transform_r = lambda n: prim.apply_permutation(perm_r, R[n])
     transform_s = lambda n: prim.apply_permutation(perm_s, S[n])
 
     # Match finding on sorted keys with *virtual* tuple IDs (line 3).
-    keys_o, vid_r, vid_s, valid, count = _find(kr, ks, mode, out_size, find_impl)
-    ID_R = jnp.where(valid, vid_r, -1)
-    ID_S = jnp.where(valid, vid_s, -1)
+    with prim.phase("probe"):
+        keys_o, vid_r, vid_s, valid, count = _find(kr, ks, mode, out_size,
+                                                   find_impl)
 
     # Materialization phase (lines 4-9): clustered gathers from transformed
     # relations, transforming remaining payload columns one at a time.
-    cols = {key: keys_o}
-    for i, n in enumerate(r_pay):
-        src = tr[n] if i == 0 else transform_r(n)
-        cols[n] = prim.gather(src, ID_R, fill=0)
-    for i, n in enumerate(s_pay):
-        src = ts[n] if i == 0 else transform_s(n)
-        cols[n] = prim.gather(src, ID_S, fill=0)
+    with prim.phase("materialize"):
+        ID_R = jnp.where(valid, vid_r, -1)
+        ID_S = jnp.where(valid, vid_s, -1)
+        cols = {key: keys_o}
+        for i, n in enumerate(r_pay):
+            src = tr[n] if i == 0 else transform_r(n)
+            cols[n] = prim.gather(src, ID_R, fill=0)
+        for i, n in enumerate(s_pay):
+            src = ts[n] if i == 0 else transform_s(n)
+            cols[n] = prim.gather(src, ID_S, fill=0)
     return Table(cols), count

@@ -12,12 +12,21 @@ at index >= count are padding; before each key-consuming operator the key
 column is re-masked to KEY_SENTINEL so padding can never match or form a
 group. Filters compact survivors to the front, which preserves the
 clustering GFTR relies on (`primitives.compact` is stable).
+
+Each operator's own work runs inside a `jax.named_scope` named for the
+node and its choice (`join.phj`, `groupby.sort`, `groupjoin.phj`,
+`filter`, `orderby`), and inside `core` each piece of work sits in one
+phase scope (`primitives.PHASES`). The scopes name the compiled
+operations' `op_name` metadata and cost nothing at run time; the served
+executable's map from instruction to scope path (`ServedProgram`) lets a
+device trace be split by operator and phase.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
 import dataclasses
+import re
 from typing import Mapping
 
 import jax
@@ -147,50 +156,53 @@ def execute(node: P.PhysNode, tables: Mapping[str, Table], counts=None):
 
 def _filter(node: P.PFilter, tables, counts=None):
     t, count = execute(node.child, tables, counts)
-    mask = FILTER_OP_FNS[node.op](t[node.column], node.value) & _valid_mask(t, count)
-    names = t.column_names
-    outs, new_count = prim.compact(mask, [t[n] for n in names], node.capacity)
+    with jax.named_scope("filter"):
+        mask = FILTER_OP_FNS[node.op](t[node.column], node.value) & _valid_mask(t, count)
+        names = t.column_names
+        outs, new_count = prim.compact(mask, [t[n] for n in names], node.capacity)
     return Table(dict(zip(names, outs))), new_count
 
 
 def _join(node: P.PJoin, tables, counts=None):
     bt, b_count = execute(node.build, tables, counts)
     pt, p_count = execute(node.probe, tables, counts)
-    bt = _mask_key(bt, b_count, node.build_key)
-    pt = _mask_key(pt, p_count, node.probe_key)
-    # core.join wants one shared key name: align build's key to the probe's
-    if node.build_key != node.probe_key:
-        bt = bt.rename({node.build_key: node.probe_key})
-    if node.algorithm == "phj" and _can_check(bt[node.probe_key],
-                                              pt[node.probe_key]):
-        out, count = phj_join_checked(
-            bt, pt, key=node.probe_key, pattern=node.pattern,
-            out_size=node.capacity, mode=node.mode,
-        )
-    else:
-        out, count = join(
-            bt, pt, key=node.probe_key, algorithm=node.algorithm,
-            pattern=node.pattern, out_size=node.capacity, mode=node.mode,
-        )
-    if node.build_key != node.probe_key:
-        # restore the equal-valued alias column (schema contract)
-        out = out.with_columns(**{node.build_key: out[node.probe_key]})
+    with jax.named_scope(f"join.{node.algorithm}"):
+        bt = _mask_key(bt, b_count, node.build_key)
+        pt = _mask_key(pt, p_count, node.probe_key)
+        # core.join wants one shared key name: align build's key to the probe's
+        if node.build_key != node.probe_key:
+            bt = bt.rename({node.build_key: node.probe_key})
+        if node.algorithm == "phj" and _can_check(bt[node.probe_key],
+                                                  pt[node.probe_key]):
+            out, count = phj_join_checked(
+                bt, pt, key=node.probe_key, pattern=node.pattern,
+                out_size=node.capacity, mode=node.mode,
+            )
+        else:
+            out, count = join(
+                bt, pt, key=node.probe_key, algorithm=node.algorithm,
+                pattern=node.pattern, out_size=node.capacity, mode=node.mode,
+            )
+        if node.build_key != node.probe_key:
+            # restore the equal-valued alias column (schema contract)
+            out = out.with_columns(**{node.build_key: out[node.probe_key]})
     return out, count
 
 
 def _group_by(node: P.PGroupBy, tables, counts=None):
     t, count = execute(node.child, tables, counts)
-    t = _mask_key(t, count, node.key)
-    sel = t.select((node.key,) + tuple(c for c, _ in node.aggs))
-    if node.strategy == "partition" and _can_check(sel[node.key]):
-        return groupby_partition_checked(
-            sel, key=node.key, aggs=dict(node.aggs),
-            num_groups=node.capacity, **dict(node.agg_kw),
+    with jax.named_scope(f"groupby.{node.strategy}"):
+        t = _mask_key(t, count, node.key)
+        sel = t.select((node.key,) + tuple(c for c, _ in node.aggs))
+        if node.strategy == "partition" and _can_check(sel[node.key]):
+            return groupby_partition_checked(
+                sel, key=node.key, aggs=dict(node.aggs),
+                num_groups=node.capacity, **dict(node.agg_kw),
+            )
+        return group_aggregate(
+            sel, key=node.key, aggs=dict(node.aggs), num_groups=node.capacity,
+            strategy=node.strategy, **dict(node.agg_kw),
         )
-    return group_aggregate(
-        sel, key=node.key, aggs=dict(node.aggs), num_groups=node.capacity,
-        strategy=node.strategy, **dict(node.agg_kw),
-    )
 
 
 def _group_join(node: P.PGroupJoin, tables, counts=None):
@@ -200,51 +212,54 @@ def _group_join(node: P.PGroupJoin, tables, counts=None):
     exists."""
     bt, b_count = execute(node.build, tables, counts)
     pt, p_count = execute(node.probe, tables, counts)
-    bt = _mask_key(bt, b_count, node.build_key)
-    pt = _mask_key(pt, p_count, node.probe_key)
-    key = node.probe_key
-    if node.build_key != key:
-        bt = bt.rename({node.build_key: key})
-    agg_cols = [c for c, _ in node.aggs]
-    b_need = dict.fromkeys([key] + [c for c in agg_cols if c in bt])
-    p_need = dict.fromkeys([key, node.probe_group_key]
-                           + [c for c in agg_cols if c in pt])
-    if _can_check(bt[key], pt[key]):
-        out, count = groupjoin_checked(
-            bt.select(tuple(b_need)), pt.select(tuple(p_need)), key=key,
-            group_key=node.probe_group_key, aggs=dict(node.aggs),
-            num_groups=node.capacity, agg_strategy=node.agg_strategy,
-            agg_kw=dict(node.agg_kw) or None,
-        )
-    else:
-        out, count = phj_groupjoin(
-            bt.select(tuple(b_need)), pt.select(tuple(p_need)), key=key,
-            group_key=node.probe_group_key, aggs=dict(node.aggs),
-            num_groups=node.capacity, agg_strategy=node.agg_strategy,
-            agg_kw=dict(node.agg_kw) or None,
-        )
-    if node.group_key != node.probe_group_key:
-        # logical schema names the group column after the GroupBy key (the
-        # equal-valued build-key alias); restore it
-        out = out.rename({node.probe_group_key: node.group_key})
+    with jax.named_scope("groupjoin.phj"):
+        bt = _mask_key(bt, b_count, node.build_key)
+        pt = _mask_key(pt, p_count, node.probe_key)
+        key = node.probe_key
+        if node.build_key != key:
+            bt = bt.rename({node.build_key: key})
+        agg_cols = [c for c, _ in node.aggs]
+        b_need = dict.fromkeys([key] + [c for c in agg_cols if c in bt])
+        p_need = dict.fromkeys([key, node.probe_group_key]
+                               + [c for c in agg_cols if c in pt])
+        if _can_check(bt[key], pt[key]):
+            out, count = groupjoin_checked(
+                bt.select(tuple(b_need)), pt.select(tuple(p_need)), key=key,
+                group_key=node.probe_group_key, aggs=dict(node.aggs),
+                num_groups=node.capacity, agg_strategy=node.agg_strategy,
+                agg_kw=dict(node.agg_kw) or None,
+            )
+        else:
+            out, count = phj_groupjoin(
+                bt.select(tuple(b_need)), pt.select(tuple(p_need)), key=key,
+                group_key=node.probe_group_key, aggs=dict(node.aggs),
+                num_groups=node.capacity, agg_strategy=node.agg_strategy,
+                agg_kw=dict(node.agg_kw) or None,
+            )
+        if node.group_key != node.probe_group_key:
+            # logical schema names the group column after the GroupBy key (the
+            # equal-valued build-key alias); restore it
+            out = out.rename({node.probe_group_key: node.group_key})
     return out, count
 
 
 def _order_by(node: P.POrderByLimit, tables, counts=None):
     t, count = execute(node.child, tables, counts)
-    k = t[node.key]
-    if node.descending:
-        # bitwise complement reverses integer order without the INT_MIN
-        # overflow of arithmetic negation; floats negate safely
-        k = ~k if jnp.issubdtype(k.dtype, jnp.integer) else -k
-    # validity is the primary sort key, so padding rows land strictly after
-    # every valid row no matter what values they carry
-    invalid = (~_valid_mask(t, count)).astype(jnp.int32)
-    iota = jnp.arange(t.num_rows, dtype=jnp.int32)
-    _, _, perm = jax.lax.sort((invalid, k, iota), num_keys=2, is_stable=True)
+    with jax.named_scope("orderby"), prim.phase("partition"):
+        k = t[node.key]
+        if node.descending:
+            # bitwise complement reverses integer order without the INT_MIN
+            # overflow of arithmetic negation; floats negate safely
+            k = ~k if jnp.issubdtype(k.dtype, jnp.integer) else -k
+        # validity is the primary sort key, so padding rows land strictly after
+        # every valid row no matter what values they carry
+        invalid = (~_valid_mask(t, count)).astype(jnp.int32)
+        iota = jnp.arange(t.num_rows, dtype=jnp.int32)
+        _, _, perm = jax.lax.sort((invalid, k, iota), num_keys=2, is_stable=True)
     # slice the permutation before gathering: top-k needs a capacity-length
     # gather, not a full-table copy of every column
-    out = t.take(perm[:node.capacity])
+    with jax.named_scope("orderby"), prim.phase("materialize"):
+        out = t.take(perm[:node.capacity])
     return out, jnp.minimum(count, node.capacity)
 
 
@@ -349,10 +364,11 @@ def run(plan: "P.PhysicalPlan", tables: Mapping[str, Table] | None = None,
 
     `counts` ({table_name: valid_count}) enables capacity bucketing
     (DESIGN.md §14): the counts ride as traced int32 scalars into a
-    SEPARATE cached executable (`plan.compiled_bucketed`), so one compiled
-    plan serves every dataset padded to its capacity buckets — the
-    count-free `plan.compiled` artifact and its jaxpr (pinned by
-    tests/test_obs.py) are untouched.
+    SEPARATE executable (`served_program`, compiled ahead of time and cached
+    on `plan.compiled_bucketed` per input shape), so one compiled plan
+    serves every dataset padded to its capacity buckets — the count-free
+    `plan.compiled` artifact and its jaxpr (pinned by tests/test_obs.py)
+    are untouched.
 
     With ``trace=True`` the plan runs node by node under the span tracer
     (repro.obs.trace) and returns ``(table, count, QueryTrace)`` — per-node
@@ -391,14 +407,11 @@ def run(plan: "P.PhysicalPlan", tables: Mapping[str, Table] | None = None,
             with checked_mode():
                 return execute(p.root, tables, counts)
         if counts is not None:
-            if p.compiled_bucketed is None:
-                p.compiled_bucketed = jax.jit(
-                    lambda tb, ct: execute(p.root, tb, ct))
-                metrics.counter("engine.plans_compiled").inc()
-            else:
-                metrics.counter("engine.plan_cache_hits").inc()
             ct = {k: jnp.asarray(v, jnp.int32) for k, v in counts.items()}
-            return p.compiled_bucketed(tables, ct)
+            prog = served_program(p, tables, ct)
+            with metrics.span("exec.run", program=prog.module,
+                              scopes=prog.scopes):
+                return prog.compiled(tables, ct)
         if p.compiled is None:
             p.compiled = jax.jit(lambda tb: execute(p.root, tb))
             metrics.counter("engine.plans_compiled").inc()
@@ -424,6 +437,91 @@ def run(plan: "P.PhysicalPlan", tables: Mapping[str, Table] | None = None,
         metrics.counter("resilience.plan_degradations").inc()
         escalation.record_degradation("executor", reason)
         return attempt(plan.degraded_plan)
+
+
+# ---------------------------------------------------------------------------
+# the served executable: compiled ahead of time, read back by a profile
+# ---------------------------------------------------------------------------
+# the node scopes `execute` opens, and the phase scopes of `core`
+_SCOPE = re.compile(r"(?:join|groupby|groupjoin)\.\w+|filter|orderby|"
+                    + "|".join(prim.PHASES))
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.-]+) = .*?"
+                    r"metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+_HEADER = re.compile(r"^(?:ENTRY )?%?([\w.-]+) ")
+_FUSION_BODY = re.compile(r" fusion\(.*?calls=%?([\w.-]+)")
+
+
+def scope_map(hlo_text: str) -> dict[str, str]:
+    """{instruction name: scope path} of a compiled program's HLO text (as
+    `Compiled.as_text()` prints it): the node and phase scopes in each
+    instruction's `op_name`, outermost first, e.g. ``"join.phj/probe"``.
+    Instructions under no scope are left out, and so are those inside
+    fusion bodies: the device runs, and a profile names, the fusion, which
+    carries the metadata of its root."""
+    fused = set(_FUSION_BODY.findall(hlo_text))
+    out: dict[str, str] = {}
+    skip = False
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace():  # a computation's header or "}"
+            m = _HEADER.match(line)
+            skip = bool(m) and m.group(1) in fused
+            continue
+        m = None if skip else _INSTR.match(line)
+        if m:
+            path = "/".join(seg for seg in m.group(2).split("/")
+                            if _SCOPE.fullmatch(seg))
+            if path:
+                out[m.group(1)] = path
+    return out
+
+
+@dataclasses.dataclass(frozen=True)
+class ServedProgram:
+    """One compiled bucketed executable of a plan, with what a profile of
+    it needs. `compiled` is the `jax.stages.Compiled` that runs (its
+    `as_text()` is the optimized HLO, instruction names as a trace shows
+    them); `module` is its HLO module name; `scopes` encodes
+    `scope_map(as_text())` for the `exec.run` span's trace metadata, as
+    ``"<path> <instruction> <instruction>|<path> ..."`` (no ``,``, ``=`` or
+    ``#``, which the profiler's metadata format reserves)."""
+
+    compiled: object
+    module: str
+    scopes: str
+
+    @classmethod
+    def of(cls, compiled) -> "ServedProgram":
+        text = compiled.as_text()
+        m = re.match(r"HloModule ([\w.-]+)", text)
+        by_path: dict[str, list] = {}
+        for name, path in scope_map(text).items():
+            by_path.setdefault(path, []).append(name)
+        scopes = "|".join(" ".join([path] + names)
+                          for path, names in sorted(by_path.items()))
+        return cls(compiled, m.group(1) if m else "", scopes)
+
+
+def served_program(plan: "P.PhysicalPlan", tables, counts) -> ServedProgram:
+    """The plan's bucketed executable for these inputs (counts as traced
+    int32 scalars, DESIGN.md §14): compiled ahead of time on first use and
+    cached on the plan, keyed by the inputs' structure, shapes, dtypes and
+    shardings as jit's own cache is, so each input shape compiles once."""
+    leaves, treedef = jax.tree_util.tree_flatten((tables, counts))
+    key = (treedef, tuple((x.shape, x.dtype, getattr(x, "sharding", None))
+                          for x in leaves))
+    prog = plan.compiled_bucketed.get(key)
+    if prog is not None:
+        metrics.counter("engine.plan_cache_hits").inc()
+        return prog
+    with metrics.span("exec.compile"):
+        def served_plan(tb, ct):
+            return execute(plan.root, tb, ct)
+
+        prog = ServedProgram.of(
+            jax.jit(served_plan).lower(tables, counts).compile())
+    plan.compiled_bucketed[key] = prog
+    metrics.counter("engine.plans_compiled").inc()
+    return prog
 
 
 # ---------------------------------------------------------------------------

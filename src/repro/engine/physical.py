@@ -39,6 +39,7 @@ from repro.core.groupby import PARTITION_ROW_BLOCK, choose_groupby_strategy
 from repro.core.hash_join import BUILD_BLOCK
 from repro.core.planner import (JoinStats, PrimitiveProfile, choose_algorithm, choose_smj_pattern,
                                 predict_groupby_time, predict_groupjoin_time, predict_join_time)
+from repro.obs import metrics
 
 from . import logical as L
 from . import stats as S
@@ -287,14 +288,14 @@ class PhysicalPlan:
     catalog: "S.Catalog"
     total_cost: float
     compiled: object = dataclasses.field(default=None, repr=False, compare=False)
-    # count-parameterized executable for the serving layer's capacity
+    # count-parameterized executables for the serving layer's capacity
     # bucketing (DESIGN.md §14): same plan, but scan valid-counts arrive as
     # traced int32 scalars so one compilation serves any dataset padded to
-    # this plan's capacity buckets. Cached separately so the count-free
-    # `compiled` artifact (and its jaxpr, pinned by tests/test_obs.py)
-    # never changes shape.
-    compiled_bucketed: object = dataclasses.field(
-        default=None, repr=False, compare=False)
+    # this plan's capacity buckets. {input shapes -> executor.ServedProgram},
+    # kept apart so the count-free `compiled` artifact (and its jaxpr,
+    # pinned by tests/test_obs.py) never changes shape.
+    compiled_bucketed: dict = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
     # "" normally; "DEGRADED[reason]" when executor.run re-planned this plan
     # after an escalation exhaustion / kernel failure (DESIGN.md §13)
     degraded: str = ""
@@ -528,7 +529,8 @@ class Optimizer:
             # silently drop survivors beyond it.
             if origin is not None:
                 col = self.catalog.tables[origin[0]][origin[1]]
-                sel = S.estimate_selectivity(col, node.op, node.value)
+                with metrics.span("plan.stats"):
+                    sel = S.estimate_selectivity(col, node.op, node.value)
             else:
                 sel = 0.33
             est = child.est_rows * sel

@@ -28,9 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hash_join import hash32
-from repro.resilience import faults
 from repro.core.planner import JoinStats
 from repro.core.table import Table
+from repro.obs import metrics
+from repro.resilience import faults
 
 from .logical import FILTER_OP_FNS
 
@@ -219,7 +220,8 @@ class Catalog:
 
     def stats(self, name: str) -> TableStats:
         if name not in self._stats:
-            self._stats[name] = collect_table_stats(self.tables[name])
+            with metrics.span("plan.stats"):
+                self._stats[name] = collect_table_stats(self.tables[name])
         return self._stats[name]
 
     def col_stats(self, name: str, col: str) -> ColumnStats:
@@ -228,7 +230,9 @@ class Catalog:
         keys) ever pay for a sketch; payload columns of wide tables don't."""
         key = (name, col)
         if key not in self._col_stats:
-            self._col_stats[key] = collect_column_stats(self.tables[name][col])
+            with metrics.span("plan.stats"):
+                self._col_stats[key] = collect_column_stats(
+                    self.tables[name][col])
         return self._col_stats[key]
 
     def selectivity(self, name: str, predicates: tuple) -> float:
@@ -238,13 +242,14 @@ class Catalog:
         that multiplying per-predicate selectivities would miss."""
         key = (name, tuple(predicates))
         if key not in self._sel:
-            t = self.tables[name]
-            mask = None
-            for col, op, value in predicates:
-                m = FILTER_OP_FNS[op](sample_column(t[col]), value)
-                mask = m if mask is None else (mask & m)
-            self._sel[key] = (1.0 if mask is None
-                              else float(jnp.mean(mask.astype(jnp.float32))))
+            with metrics.span("plan.stats"):
+                t = self.tables[name]
+                mask = None
+                for col, op, value in predicates:
+                    m = FILTER_OP_FNS[op](sample_column(t[col]), value)
+                    mask = m if mask is None else (mask & m)
+                self._sel[key] = (1.0 if mask is None
+                                  else float(jnp.mean(mask.astype(jnp.float32))))
         return self._sel[key]
 
     def max_multiplicity(self, origin: tuple[str, str],
@@ -255,14 +260,15 @@ class Catalog:
         (key, valid) pairs + validity prefix sums, one scalar transfer."""
         key = (origin, tuple(preds))
         if key not in self._mult:
-            keys, mask = self._masked_keys(origin, preds)
-            sk, valid = jax.lax.sort((keys, mask.astype(jnp.int32)), num_keys=1)
-            cum = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                   jnp.cumsum(valid)])
-            lo = jnp.searchsorted(sk, sk, side="left")
-            hi = jnp.searchsorted(sk, sk, side="right")
-            per = jnp.take(cum, hi) - jnp.take(cum, lo)
-            self._mult[key] = float(jnp.max(jnp.where(valid > 0, per, 0)))
+            with metrics.span("plan.stats"):
+                keys, mask = self._masked_keys(origin, preds)
+                sk, valid = jax.lax.sort((keys, mask.astype(jnp.int32)), num_keys=1)
+                cum = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                       jnp.cumsum(valid)])
+                lo = jnp.searchsorted(sk, sk, side="left")
+                hi = jnp.searchsorted(sk, sk, side="right")
+                per = jnp.take(cum, hi) - jnp.take(cum, lo)
+                self._mult[key] = float(jnp.max(jnp.where(valid > 0, per, 0)))
         return self._mult[key]
 
     def is_unique(self, name: str, col: str) -> bool:
@@ -273,8 +279,9 @@ class Catalog:
         pk_fk plan and one that silently drops duplicate matches."""
         key = (name, col)
         if key not in self._unique:
-            s = jnp.sort(self.tables[name][col])
-            self._unique[key] = not bool(jnp.any(s[1:] == s[:-1]))
+            with metrics.span("plan.stats"):
+                s = jnp.sort(self.tables[name][col])
+                self._unique[key] = not bool(jnp.any(s[1:] == s[:-1]))
         return self._unique[key]
 
     def match_ratio(self, build_origin: tuple[str, str],
@@ -289,13 +296,14 @@ class Catalog:
         restriction and the join capacity silently truncates."""
         key = (build_origin, probe_origin, tuple(probe_predicates))
         if key not in self._mr:
-            probe_t = self.tables[probe_origin[0]]
-            bk = jnp.sort(self.tables[build_origin[0]][build_origin[1]])
-            pk = sample_column(probe_t[probe_origin[1]])
-            mask = jnp.ones(pk.shape, bool)
-            for col, op, value in probe_predicates:
-                mask &= FILTER_OP_FNS[op](sample_column(probe_t[col]), value)
-            self._mr[key] = _membership_ratio(bk, pk, mask)
+            with metrics.span("plan.stats"):
+                probe_t = self.tables[probe_origin[0]]
+                bk = jnp.sort(self.tables[build_origin[0]][build_origin[1]])
+                pk = sample_column(probe_t[probe_origin[1]])
+                mask = jnp.ones(pk.shape, bool)
+                for col, op, value in probe_predicates:
+                    mask &= FILTER_OP_FNS[op](sample_column(probe_t[col]), value)
+                self._mr[key] = _membership_ratio(bk, pk, mask)
         return self._mr[key]
 
     def _masked_keys(self, origin: tuple[str, str], predicates: tuple):
@@ -323,15 +331,16 @@ class Catalog:
         key = tuple(sorted(((a_origin, tuple(a_preds)),
                             (b_origin, tuple(b_preds)))))
         if key not in self._mn_rows:
-            a, ma = self._masked_keys(a_origin, a_preds)
-            b, mb = self._masked_keys(b_origin, b_preds)
-            sb, valid_b = jax.lax.sort((b, mb.astype(jnp.int32)), num_keys=1)
-            cum = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                   jnp.cumsum(valid_b)])
-            lo = jnp.searchsorted(sb, a, side="left")
-            hi = jnp.searchsorted(sb, a, side="right")
-            per_a = (jnp.take(cum, hi) - jnp.take(cum, lo)).astype(jnp.float32)
-            self._mn_rows[key] = float(jnp.sum(jnp.where(ma, per_a, 0.0)))
+            with metrics.span("plan.stats"):
+                a, ma = self._masked_keys(a_origin, a_preds)
+                b, mb = self._masked_keys(b_origin, b_preds)
+                sb, valid_b = jax.lax.sort((b, mb.astype(jnp.int32)), num_keys=1)
+                cum = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                       jnp.cumsum(valid_b)])
+                lo = jnp.searchsorted(sb, a, side="left")
+                hi = jnp.searchsorted(sb, a, side="right")
+                per_a = (jnp.take(cum, hi) - jnp.take(cum, lo)).astype(jnp.float32)
+                self._mn_rows[key] = float(jnp.sum(jnp.where(ma, per_a, 0.0)))
         return self._mn_rows[key]
 
 

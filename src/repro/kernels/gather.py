@@ -78,6 +78,7 @@ def gather_windowed_pallas(
     )
     out = pl.pallas_call(
         functools.partial(_gather_kernel, window_rows),
+        name="clustered_gather",
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tile), src.dtype),
         interpret=resolve_interpret(interpret),

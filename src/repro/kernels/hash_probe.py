@@ -74,6 +74,7 @@ def hash_probe_pallas(
     )
     vid, hit = pl.pallas_call(
         _probe_kernel,
+        name="hash_probe",
         grid_spec=spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, 1, capS), jnp.int32),
@@ -176,6 +177,7 @@ def probe_agg_pallas(
     )
     pk, ps, pc = pl.pallas_call(
         functools.partial(_probe_agg_kernel, col_sides=tuple(col_sides)),
+        name="probe_agg",
         grid_spec=spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, 1, capS), gk_blocks.dtype),

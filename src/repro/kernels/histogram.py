@@ -44,6 +44,7 @@ def histogram_pallas(
     grid = d2.shape[0] // block_rows
     out = pl.pallas_call(
         functools.partial(_hist_kernel, num_bins),
+        name="histogram",
         grid=(grid,),
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((num_bins, LANES), lambda i: (0, 0)),

@@ -80,6 +80,7 @@ def lower_bound_windowed_pallas(
     )
     out = pl.pallas_call(
         functools.partial(_lb_kernel, window_rows),
+        name="merge_lower_bound",
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tile), jnp.int32),
         interpret=resolve_interpret(interpret),

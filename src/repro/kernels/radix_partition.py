@@ -65,6 +65,7 @@ def _block_histograms_t(d2, num_bins: int, block_rows: int, interpret: bool):
     grid = d2.shape[0] // block_rows
     return pl.pallas_call(
         functools.partial(_block_hist_kernel, num_bins),
+        name="block_histograms",
         grid=(grid,),
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((num_bins, LANES), lambda i: (0, i // LANES)),
@@ -140,6 +141,7 @@ def partition_ranks_pallas(
     base = (offsets[:, None] + jnp.cumsum(bh, axis=1) - bh).astype(jnp.int32)
     dest = pl.pallas_call(
         functools.partial(_rank_kernel, num_bins),
+        name="partition_ranks",
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),

@@ -67,6 +67,7 @@ def segsum_partials_pallas(
     row = pl.BlockSpec((None, 1, tile), lambda i: (i, 0, 0))
     pk, ps, pc = pl.pallas_call(
         _segsum_kernel,
+        name="segsum_partials",
         grid=(n_tiles,),
         in_specs=[row, row],
         out_specs=[row, row, row],

@@ -1,5 +1,5 @@
 """`python -m repro.obs` — run the standard traced workload, write
-TRACE.json (+ TRACE.perfetto.json), update CALIBRATION.json, and print
+TRACE.json, update CALIBRATION.json, and print
 the predicted-vs-measured table per plan node (DESIGN.md §12).
 
 Two optimizer-chosen queries cover the residual surfaces that matter:
@@ -69,7 +69,6 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes for CI (seconds, not minutes)")
     ap.add_argument("--trace-out", default="TRACE.json")
-    ap.add_argument("--perfetto-out", default="TRACE.perfetto.json")
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--warmup", type=int, default=1)
     args = ap.parse_args(argv)
@@ -97,11 +96,6 @@ def main(argv=None) -> int:
                   f, indent=2, sort_keys=True)
     print(f"\nwrote {args.trace_out} "
           f"({sum(len(t.spans()) for t in traces.values())} spans)")
-    events = [dict(e, pid=i) for i, t in enumerate(traces.values())
-              for e in t.chrome_trace()]
-    with open(args.perfetto_out, "w") as f:
-        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
-    print(f"wrote {args.perfetto_out} (Perfetto-loadable)")
 
     # feed the residuals back: profile stays (calibrated_profile already
     # persisted it), EWMAs sharpen with this run's measured/modeled ratios

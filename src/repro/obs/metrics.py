@@ -35,11 +35,29 @@ Usage::
 
     metrics.counter("engine.plans_compiled").inc()
     metrics.histogram("engine.run_wall_s").observe(dt)
+    with metrics.span("qserve.pad"):   # wall seconds -> histogram("qserve.pad")
+        ...
     metrics.snapshot()   # {name: value | summary-dict}, for reporting
+
+Spans (`span`) time one piece of host work where it happens. Each adds
+its wall seconds to the histogram of its name and opens a
+`jax.profiler.TraceAnnotation` of that name, so under a profiler the span
+lands on the host plane of the same trace as the device's operations, on
+their clock. The served path's spans: `qserve.signature` (the plan
+signature's hash per submission), `qserve.pad` (eager padding of a run's
+inputs), `qserve.dispatch` (handing a run to the executor),
+`qserve.count_sync` (the host waiting on the result count),
+`plan.stats` (each catalog statistic computed rather than looked up),
+`plan.audit` (a signature's peak-bytes audit and morsel probing),
+`exec.compile` (lowering and compiling a served executable, or loading it
+from the persistent cache) and `exec.run` (calling it; its trace event
+carries the executable's scope map, see `engine.executor.ServedProgram`).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
 
 
 @dataclasses.dataclass
@@ -175,6 +193,23 @@ def counter(name: str) -> Counter:
 
 def histogram(name: str) -> Histogram:
     return REGISTRY.histogram(name)
+
+
+@contextlib.contextmanager
+def span(name: str, **metadata):
+    """Time the enclosed host work into `histogram(name)` and mark it as a
+    `jax.profiler.TraceAnnotation` (with `metadata` as the event's stats;
+    they are formatted only while a profiler records). Without a profiler
+    a span costs a `perf_counter` pair, an inactive TraceMe and an observe:
+    a few microseconds."""
+    from jax.profiler import TraceAnnotation  # lazy: this module imports no jax
+
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(name, **metadata):
+            yield
+    finally:
+        REGISTRY.histogram(name).observe(time.perf_counter() - t0)
 
 
 def snapshot() -> dict:

@@ -14,8 +14,7 @@ The result is a `QueryTrace` tree of `Span`s carrying, per node:
     bytes_in/out  device bytes entering/leaving (capacity x itemsize)
     strategy      the chosen algorithm/pattern or group-by strategy
 
-exportable as JSON (`as_dict`/`to_json`) and as Chrome trace-event format
-(`chrome_trace`/`to_chrome_trace` — loadable in Perfetto / about:tracing).
+exportable as JSON (`as_dict`/`to_json`).
 
 Tracing is strictly opt-in: `executor.run(plan)` without `trace=True`
 never imports this module's machinery, allocates no `Span`, and compiles
@@ -84,7 +83,6 @@ class Span:
     rows_out: int
     bytes_in: int
     bytes_out: int
-    t0_s: float  # offset of the timed window from the trace start
     children: list = dataclasses.field(default_factory=list)
 
     # allocation counter pinning the zero-overhead contract: an untraced
@@ -174,26 +172,6 @@ class QueryTrace:
     def to_json(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.as_dict(), f, indent=2, sort_keys=True)
-
-    def chrome_trace(self) -> list:
-        """Chrome trace-event list (Perfetto / about:tracing loadable):
-        one complete ('X') event per span on a single track, timestamps
-        in microseconds from the trace start."""
-        events = []
-        for s in self.spans():
-            events.append({
-                "name": f"{s.op}[{s.strategy}]" if s.strategy else s.op,
-                "cat": "plan-node", "ph": "X",
-                "ts": s.t0_s * 1e6, "dur": max(s.wall_s, 1e-9) * 1e6,
-                "pid": 0, "tid": 0,
-                "args": s.as_dict(),
-            })
-        return events
-
-    def to_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump({"traceEvents": self.chrome_trace(),
-                       "displayTimeUnit": "ms"}, f, indent=2)
 
     def table(self) -> str:
         """Human-readable predicted-vs-measured table, one row per node."""
@@ -309,7 +287,6 @@ def trace_execute(plan, tables=None, *, iters: int = 1, warmup: int = 1,
             args = (child_out,)
             rows_in = sum(int(c) for _, c in child_out)
             bytes_in = sum(_table_bytes(t) + 4 for t, _ in child_out)
-        t0 = time.perf_counter() - t_begin
         (out_t, out_c), wall = timed_call(fn, *args, iters=iters,
                                           warmup=warmup)
         span = Span(
@@ -318,7 +295,7 @@ def trace_execute(plan, tables=None, *, iters: int = 1, warmup: int = 1,
             predicted_s=float(node.cost), wall_s=wall,
             rows_in=rows_in, rows_out=int(out_c),
             bytes_in=bytes_in, bytes_out=_table_bytes(out_t) + 4,
-            t0_s=t0, children=child_spans,
+            children=child_spans,
         )
         return (out_t, out_c), span
 

@@ -136,7 +136,9 @@ def _saturated(root, count) -> bool:
     (top-k fills its limit); scans/projects are full-width by contract."""
     if not isinstance(root, (P.PFilter, P.PJoin, P.PGroupBy, P.PGroupJoin)):
         return False
-    return int(count) >= root.capacity
+    with metrics.span("qserve.count_sync"):  # the host waits on the device
+        n = int(count)
+    return n >= root.capacity
 
 
 # ---------------------------------------------------------------------------
@@ -380,7 +382,8 @@ class QueryServer:
 
     def _ensure_entry(self, req: QueryRequest) -> CompiledEntry:
         t0 = time.perf_counter()
-        sig, buckets = plan_signature(req.plan, req.tables)
+        with metrics.span("qserve.signature"):
+            sig, buckets = plan_signature(req.plan, req.tables)
         req.signature = sig
         if sig not in self.breakers:
             self.breakers[sig] = CircuitBreaker(sig, **self.breaker_kw)
@@ -397,7 +400,8 @@ class QueryServer:
                               measure_profile=self.measure_profile)
             entry = CompiledEntry(signature=sig, buckets=buckets, plan=phys,
                                   price_s=float(phys.total_cost))
-            self._size_entry(entry, padded)
+            with metrics.span("plan.audit"):
+                self._size_entry(entry, padded)
             self.cache[sig] = entry
             metrics.counter("qserve.plans_compiled").inc()
         else:
@@ -535,8 +539,9 @@ class QueryServer:
 
     # -- execution -----------------------------------------------------------
     def _pad_inputs(self, entry: CompiledEntry, req: QueryRequest):
-        padded = {n: pad_table(t, entry.buckets[n])
-                  for n, t in req.tables.items()}
+        with metrics.span("qserve.pad"):
+            padded = {n: pad_table(t, entry.buckets[n])
+                      for n, t in req.tables.items()}
         counts = {n: t.num_rows for n, t in req.tables.items()}
         return padded, counts
 
@@ -546,13 +551,15 @@ class QueryServer:
         if entry.morsel_factor > 1:
             # budget-sized signature: out-of-core morsel path, one chunk
             # at a time through the cached morsel clone's executable
-            out, count = executor.run_morsels(
-                entry.plan, padded, counts=counts,
-                factor=entry.morsel_factor)
+            with metrics.span("qserve.dispatch"):
+                out, count = executor.run_morsels(
+                    entry.plan, padded, counts=counts,
+                    factor=entry.morsel_factor)
             metrics.counter("qserve.chunked_runs").inc()
             req.morsels = entry.morsel_factor
         else:
-            out, count = executor.run(entry.plan, padded, counts=counts)
+            with metrics.span("qserve.dispatch"):
+                out, count = executor.run(entry.plan, padded, counts=counts)
         metrics.counter("qserve.fast_runs").inc()
         if _saturated(entry.plan.root, count):
             metrics.counter("qserve.saturations").inc()

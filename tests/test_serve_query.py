@@ -93,7 +93,7 @@ def test_executor_counts_reuse_one_compiled_plan():
         counts = {n: t.num_rows for n, t in tb.items()}
         got = canon(*plan.run(padded, counts=counts))
         assert got == one_shot(JOIN_PLAN, tb)
-    assert plan.compiled_bucketed is not None
+    assert len(plan.compiled_bucketed) == 1  # one executable, both datasets
     assert plan.compiled is None  # the legacy slot never materialized
 
 

@@ -5,7 +5,8 @@ compiler refuses: blocks that break the (8, 128) tiling rule, in-kernel
 shape casts, VMEM overflow. These tests lower each kernel with
 `interpret=False` at a real block size (2^22 rows) for one chip of a
 described `v5e:2x2` topology — nothing runs, no chip is needed — and check
-that the compiled program carries the kernel (`tpu_custom_call`).
+that the compiled program carries the kernel (`tpu_custom_call`), under the
+stable name its `pallas_call` gives it (the name a device profile shows).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -14,6 +15,7 @@ imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +62,7 @@ def _compile(fn, one_chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 I32, F32 = jnp.int32, jnp.float32
@@ -115,3 +118,24 @@ KERNELS = {
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = KERNELS[name]
     _compile(fn, one_chip, *shapes)
+
+
+# each kernel's `pallas_call(name=...)` -> an entry of KERNELS that runs it
+NAMED = {
+    "block_histograms": "block_histograms",
+    "partition_ranks": "partition_ranks",
+    "histogram": "histogram",
+    "clustered_gather": "gather_windowed_i32",
+    "merge_lower_bound": "lower_bound_windowed",
+    "hash_probe": "hash_probe",
+    "probe_agg": "probe_agg",
+    "segsum_partials": "segsum_partials",
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(NAMED))
+def test_compiled_kernel_carries_its_name(one_chip, kernel):
+    fn, shapes = KERNELS[NAMED[kernel]]
+    text = _compile(fn, one_chip, *shapes)
+    assert re.search(rf'%{kernel}(\.\d+)? = .*custom_call_target="tpu_custom_call"',
+                     text), f"no tpu_custom_call named {kernel}"
