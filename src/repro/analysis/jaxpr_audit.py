@@ -158,7 +158,9 @@ def budget_of_jaxpr(jaxpr) -> PrimitiveBudget:
 # ---------------------------------------------------------------------------
 def _itemsize(aval) -> int:
     dt = getattr(aval, "dtype", None)
-    return 0 if dt is None else np.dtype(dt).itemsize
+    if dt is None or jax.dtypes.issubdtype(dt, jax.dtypes.extended):
+        return 0  # no array, or a kernel's DMA semaphore: no bytes moved
+    return np.dtype(dt).itemsize
 
 
 def find_promotions(jaxpr) -> tuple:

@@ -167,7 +167,8 @@ def phj_groupjoin(
                 # through the matched virtual IDs (clustered within
                 # co-partitions — the same access shape as phj_join's ID_R)
                 tr = prim.apply_permutation(perm_r, R[col])
-                cols[col] = prim.gather(tr, jnp.where(matched, vid_r, -1), fill=0)
+                cols[col] = prim.clustered_gather(
+                    tr, jnp.where(matched, vid_r, -1))
 
     return group_aggregate(Table(cols), key=group_key, aggs=aggs,
                            num_groups=num_groups, strategy=agg_strategy,

@@ -193,7 +193,6 @@ def phj_join(
     hash_keys: bool = True,
     probe_chunk: int = 8192,
     probe_impl: str = "xla",  # "xla" | "pallas" (co-partition probe kernel)
-    gather_impl: str = "xla",  # "xla" | "pallas" (windowed clustered gather)
 ):
     """End-to-end partitioned hash join. Returns (Table, valid_count).
 
@@ -272,18 +271,16 @@ def phj_join(
             # OM: gather from partitioned relations. Probe-side IDs are
             # perfectly clustered; build-side IDs are clustered within
             # partitions (§4.3).
-            if gather_impl == "pallas":
-                from repro.kernels import ops as _kops
-
-                _g = lambda src, idx: _kops.clustered_gather(src, idx, "auto")
-            else:
-                _g = lambda src, idx: prim.gather(src, idx, fill=0)
-            for n in r_pay:
-                tr_n = prim.apply_permutation(perm_r, R[n])  # col n's ONE gather
-                cols[n] = _g(tr_n, ID_R)
-            for n in s_pay:
-                ts_n = prim.apply_permutation(perm_s, S[n])
-                cols[n] = _g(ts_n, ID_S)
+            # One column at a time (Algorithm 1's lazy transform): each
+            # column's transform waits for the previous column's gather,
+            # so a single transformed column is live at once.
+            prev = ID_R
+            for T, perm, ids, names in ((R, perm_r, ID_R, r_pay),
+                                        (S, perm_s, ID_S, s_pay)):
+                for n in names:
+                    col, _ = jax.lax.optimization_barrier((T[n], prev))
+                    tr_n = prim.apply_permutation(perm, col)  # col n's ONE gather
+                    cols[n] = prev = prim.clustered_gather(tr_n, ids)
         else:
             raise ValueError(f"unknown pattern {pattern!r}")
 

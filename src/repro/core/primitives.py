@@ -269,6 +269,17 @@ def gather(src: jax.Array, idx: jax.Array, *, fill=None) -> jax.Array:
     return out
 
 
+def clustered_gather(src: jax.Array, idx: jax.Array) -> jax.Array:
+    """GATHER through a clustered map — GFTR's second gather, from a
+    transformed relation: out[i] = src[idx[i]], 0 where idx is out of range
+    (`gather(..., fill=0)`). Runs the windowed VMEM kernel where the
+    backend compiles Pallas, XLA's take otherwise
+    (`kernels.ops.clustered_gather`)."""
+    from repro.kernels import ops as kops
+
+    return kops.clustered_gather(src, idx)
+
+
 def histogram(x: jax.Array, num_bins: int) -> jax.Array:
     return jnp.bincount(x, length=num_bins)
 

@@ -161,8 +161,8 @@ def _smj_gftr(R, S, key, r_pay, s_pay, out_size, mode, find_impl="xla"):
         cols = {key: keys_o}
         for i, n in enumerate(r_pay):
             src = tr[n] if i == 0 else transform_r(n)
-            cols[n] = prim.gather(src, ID_R, fill=0)
+            cols[n] = prim.clustered_gather(src, ID_R)
         for i, n in enumerate(s_pay):
             src = ts[n] if i == 0 else transform_s(n)
-            cols[n] = prim.gather(src, ID_S, fill=0)
+            cols[n] = prim.clustered_gather(src, ID_S)
     return Table(cols), count

@@ -3,7 +3,9 @@
 Dispatch policy mirrors the paper's planner logic: the windowed (clustered)
 kernels are only profitable/correct when the gather map / merge frontier is
 clustered, so each wrapper measures the per-tile span (cheap, O(n/tile)) and
-falls back to XLA's random-access path otherwise.
+falls back to XLA's random-access path otherwise. `clustered_gather` needs
+no such choice: its kernel is exact for any index and costs one VMEM
+window per window of each tile's span, measured on the device.
 
 Execution mode is resolved per call (`common.resolve_interpret`): compiled
 kernels on TPU, interpret mode elsewhere; REPRO_PALLAS_INTERPRET=0/1
@@ -20,7 +22,7 @@ import jax.numpy as jnp
 from repro.resilience import faults
 
 from . import ref
-from .common import ceil_div
+from .common import ceil_div, default_interpret
 from .gather import gather_windowed_pallas
 from .hash_probe import hash_probe_pallas, layout_probe_blocks, probe_agg_pallas
 from .histogram import histogram_pallas
@@ -406,35 +408,37 @@ def clustered_gather(
     idx: jax.Array,
     impl: str = "auto",
     *,
-    window_rows: int = 1024,
+    window_rows: int = 2048,
     tile: int = 1024,
 ):
-    """GATHER with windowed-kernel dispatch. Invalid idx (<0) -> 0."""
-    safe_idx = jnp.clip(idx, 0, src.shape[0] - 1)
-    if impl == "xla":
-        out = jnp.take(src, safe_idx, axis=0)
-        return jnp.where(idx >= 0, out, 0)
-    n = idx.shape[0]
-    n_tiles = ceil_div(n, tile)
-    t0 = safe_idx[::tile]
-    win_idx = t0 // window_rows
-    if impl == "auto":
-        tile_pad = jnp.pad(safe_idx, (0, n_tiles * tile - n)).reshape(n_tiles, tile)
-        spans_ok = bool(jnp.all(tile_pad.max(1) < (win_idx + 2) * window_rows)
-                        & jnp.all(tile_pad.min(1) >= win_idx * window_rows))
-        if not spans_ok:
-            out = jnp.take(src, safe_idx, axis=0)
-            return jnp.where(idx >= 0, out, 0)
+    """GATHER through a clustered map: out[i] = src[idx[i]], 0 where idx < 0
+    or idx >= len(src) (`primitives.gather`'s fill=0 contract).
 
-    def pallas_arm():
-        out = gather_windowed_pallas(
-            src, safe_idx, win_idx, window_rows=window_rows, tile=tile,
-            interpret=None)
-        return jnp.where(idx >= 0, out, 0)
+    impl='auto' runs the windowed kernel where the backend compiles Pallas
+    and XLA's take under interpret mode; 'pallas' forces the kernel, 'xla'
+    the take. The kernel moves the 32-bit words of a 1-D source; any other
+    source takes XLA's path. The kernel is exact for any index, and its
+    cost follows each output tile's span (`gather.window_plan`, computed on
+    the device): one window for the clustered maps GFTR makes, so the
+    choice needs no host sync and no XLA branch beside the kernel."""
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(f"unknown clustered gather impl {impl!r}")
+    n_src = src.shape[0]
 
+    def xla_arm():
+        out = jnp.take(src, jnp.clip(idx, 0, n_src - 1), axis=0)
+        valid = (idx >= 0) & (idx < n_src)
+        return jnp.where(valid.reshape(valid.shape + (1,) * (out.ndim - 1)),
+                         out, jnp.zeros_like(out))
+
+    if (impl == "xla" or (impl == "auto" and default_interpret())
+            or src.ndim != 1 or src.dtype.itemsize != 4):
+        return xla_arm()
     return _pallas_arm(
-        "clustered_gather", pallas_arm,
-        lambda: jnp.where(idx >= 0, jnp.take(src, safe_idx, axis=0), 0))
+        "clustered_gather",
+        lambda: gather_windowed_pallas(src, idx, window_rows=window_rows,
+                                       tile=tile, interpret=None),
+        xla_arm)
 
 
 # ---------------------------------------------------------------------------

@@ -121,9 +121,34 @@ def test_kernel_backed_paths_match_xla(rng):
     expected = oracle_join(rk, rp, sk, sp)
     T1, c1 = join(R, S, algorithm="smj", pattern="gftr", find_impl="pallas")
     T2, c2 = join(R, S, algorithm="phj", pattern="gftr",
-                  probe_impl="pallas", gather_impl="pallas")
+                  probe_impl="pallas")
     assert result_rows(T1, c1, ["r0", "r1"], ["s0"]) == expected
     assert result_rows(T2, c2, ["r0", "r1"], ["s0"]) == expected
+
+
+@pytest.mark.parametrize("alg", ["phj", "smj"])
+def test_gftr_clustered_gather_kernel_matches_the_xla_arm(alg, rng, monkeypatch):
+    """GFTR's clustered output gathers through the windowed kernel give the
+    XLA arm's output row for row, the fill of the rows past the count too."""
+    import jax
+
+    from repro.kernels import ops
+
+    R, S, *_ = make_tables(rng, 3000, 5000, 3, 2, match_ratio=0.7)
+    run = lambda: jax.jit(lambda R, S: join(R, S, algorithm=alg,
+                                            pattern="gftr"))(R, S)
+    T1, c1 = run()
+    calls = []
+    real = ops.gather_windowed_pallas
+    monkeypatch.setattr(ops, "gather_windowed_pallas",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    # as on a backend that compiles Pallas: 'auto' takes the kernel
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    T2, c2 = run()
+    assert len(calls) == 5  # every payload column, both sides
+    assert int(c1) == int(c2)
+    for n in T1.column_names:
+        np.testing.assert_array_equal(np.asarray(T1[n]), np.asarray(T2[n]))
 
 
 @settings(max_examples=15, deadline=None)

@@ -133,6 +133,92 @@ def test_gather_unclustered_fallback(rng):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(jnp.take(src, idx)))
 
 
+def _fill_take(src, idx):
+    """`primitives.gather(src, idx, fill=0)` in NumPy: the oracle."""
+    src, idx = np.asarray(src), np.asarray(idx)
+    ok = (idx >= 0) & (idx < src.shape[0])
+    return np.where(ok, src[np.clip(idx, 0, src.shape[0] - 1)], src.dtype.type(0))
+
+
+def _phj_build_ids(rng):
+    """ID_R of a small PHJ-OM join: the build-side virtual IDs of its
+    output, clustered within co-partitions but not monotone, -1 past the
+    matches."""
+    from repro.core import Table, primitives as prim
+    from repro.core.hash_join import phj_join
+
+    seen = []
+    real = prim.clustered_gather
+
+    def record(src, idx):
+        seen.append(idx)
+        return real(src, idx)
+
+    R = Table({"k": jnp.asarray(rng.permutation(6000).astype(np.int32)),
+               "r0": jnp.zeros((6000,), jnp.int32)})
+    S = Table({"k": jnp.asarray(rng.integers(0, 9000, 5000).astype(np.int32))})
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(prim, "clustered_gather", record)
+        phj_join(R, S, build_block=64, out_size=5120)
+    return np.asarray(seen[0])
+
+
+def _gather_case(name, rng):
+    """(idx, tile) for a source of 6000 rows gathered in windows of 512."""
+    if name == "probe_ids":  # monotone, -1 tail, fully invalid tiles
+        idx = np.full(4096, -1, np.int32)
+        idx[:2500] = np.sort(rng.choice(6000, 2500, replace=False))
+        return idx, 128
+    if name == "build_ids":
+        return _phj_build_ids(rng), 128
+    if name == "min_below_first":  # the first index is not the smallest
+        idx = np.arange(1000, 1512, dtype=np.int32)
+        idx[0], idx[7] = 1400, 910
+        return idx, 256
+    # too wide for any window: every tile spans the whole source
+    return rng.permutation(6000).astype(np.int32)[:2048], 256
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.float32])
+@pytest.mark.parametrize("case", ["probe_ids", "build_ids", "min_below_first",
+                                  "too_wide"])
+def test_clustered_gather_kernel_under_jit(case, dtype, rng):
+    """The kernel arm, compiled by jit, equals the fill=0 take bit for bit,
+    whatever the spans; a clustered map needs one window per tile."""
+    from repro.kernels.gather import window_plan
+
+    # random words: as floats they hold NaNs, infinities and -0.0 too
+    src = rng.integers(-(1 << 31), (1 << 31) - 1, 6000, dtype=np.int64)
+    src = src.astype(np.int32).view(dtype)
+    idx, tile = _gather_case(case, rng)
+    fn = jax.jit(lambda s, i: ops.clustered_gather(s, i, "pallas",
+                                                   window_rows=512, tile=tile))
+    out = np.asarray(fn(jnp.asarray(src), jnp.asarray(idx)))
+    np.testing.assert_array_equal(out.view(np.int32),
+                                  _fill_take(src, idx).view(np.int32))
+    win, n_win = (np.asarray(a) for a in window_plan(
+        jnp.asarray(idx), 6000, window_rows=512, tile=tile))
+    tiles = np.pad(idx, (0, -len(idx) % tile), constant_values=-1).reshape(-1, tile)
+    dead = (tiles < 0).all(axis=1)
+    assert ((win < 0) == dead).all() and ((n_win == 0) == dead).all()
+    assert n_win.max() > 1 if case == "too_wide" else n_win.max() == 1
+    if case == "probe_ids":
+        assert dead.sum() >= 12  # the -1 tail fills whole tiles
+    if case == "min_below_first":
+        assert win[0] == 910 // 128  # the smallest index's block, not the first's
+
+
+def test_clustered_gather_traces_without_values(monkeypatch):
+    """The default dispatch, as on a backend that compiles Pallas, traces
+    from shapes alone: nothing in it asks the device for a value (a host
+    sync would fail here), and it reaches the kernel."""
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    src = jax.ShapeDtypeStruct((1 << 14,), jnp.int32)
+    idx = jax.ShapeDtypeStruct((3 << 12,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda s, i: ops.clustered_gather(s, i))(src, idx)
+    assert "pallas_call" in str(jaxpr)
+
+
 @settings(max_examples=10, deadline=None)
 @given(n=st.integers(1, 3000), g=st.integers(1, 100), tile=st.sampled_from([64, 256]),
        seed=st.integers(0, 2**31 - 1))

@@ -173,6 +173,23 @@ def test_plan_peak_bytes_positive_and_counts_invariant():
     assert plan_peak_bytes(plan, tables, counts=counts) > 0
 
 
+def test_plan_audit_and_answers_with_the_clustered_gather_kernel(monkeypatch):
+    """A PHJ-OM plan whose output gathers run the windowed kernel (as on a
+    backend that compiles Pallas): the peak-bytes audit walks the kernel's
+    DMA semaphore, and the answers equal the XLA arm's."""
+    from repro.kernels import ops
+
+    tables = make_join_tables()
+    plan = optimize(scan("S").join(scan("R"), key="k"), Catalog(tables),
+                    measure_profile=False, force_join=("phj", "gftr"))
+    oracle = canon(*xrun(plan))
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    plan = optimize(scan("S").join(scan("R"), key="k"), Catalog(tables),
+                    measure_profile=False, force_join=("phj", "gftr"))
+    assert plan_peak_bytes(plan) > 0
+    assert canon(*xrun(plan)) == oracle
+
+
 # ---------------------------------------------------------------------------
 # morsel-split group-by: bit identity across every strategy (property)
 # ---------------------------------------------------------------------------

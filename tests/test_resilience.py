@@ -303,8 +303,7 @@ def _site_cases(rng):
     probe = jnp.sort(jnp.asarray(
         rng.integers(0, 1 << 16, 2048).astype(np.int32)))
     src = jnp.asarray(rng.integers(0, 99, 4096).astype(np.int32))
-    # clustered, monotone indices: impl='pallas' skips the span check, so
-    # the data must genuinely satisfy the windowed kernel's precondition
+    # clustered, monotone indices: one window per tile of the kernel
     idx = jnp.repeat(jnp.arange(1024, dtype=jnp.int32) * 2, 2)
     skeys = jnp.sort(jnp.asarray(rng.integers(0, 64, 2048).astype(np.int32)))
     vals = jnp.asarray(rng.random(2048).astype(np.float32))

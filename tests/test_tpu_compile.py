@@ -34,7 +34,7 @@ from repro.kernels.segsum import segsum_partials_pallas
 ROWS = 1 << 22
 CAP = 256  # core.hash_join.BUILD_BLOCK: build block / probe sub-block width
 PARTS = 1 << 16  # PHJ fan-out at 2^22 build rows (choose_partition_bits)
-TILE = 1024  # gather / merge tile and window (kernels.ops defaults)
+TILE = 1024  # merge tile and window (kernels.ops defaults)
 
 
 @pytest.fixture(scope="module")
@@ -85,13 +85,15 @@ KERNELS = {
         lambda d: histogram_pallas(d, 256, interpret=False),
         [((ROWS,), I32)]),
     "gather_windowed_f32": (
-        lambda s, i, w: gather_windowed_pallas(s, i, w, window_rows=TILE,
-                                               tile=TILE, interpret=False),
-        [((ROWS,), F32), ((ROWS,), I32), ((ROWS // TILE,), I32)]),
+        lambda s, i: gather_windowed_pallas(s, i, interpret=False),
+        [((ROWS,), F32), ((ROWS,), I32)]),
     "gather_windowed_i32": (
-        lambda s, i, w: gather_windowed_pallas(s, i, w, window_rows=TILE,
-                                               tile=TILE, interpret=False),
-        [((ROWS,), I32), ((ROWS,), I32), ((ROWS // TILE,), I32)]),
+        lambda s, i: gather_windowed_pallas(s, i, interpret=False),
+        [((ROWS,), I32), ((ROWS,), I32)]),
+    # the Q7 join's shapes: a 2^24-row bucket into its 29,982,720-row output
+    "gather_windowed_q7": (
+        lambda s, i: gather_windowed_pallas(s, i, interpret=False),
+        [((1 << 24,), I32), ((29_982_720,), I32)]),
     "lower_bound_windowed": (
         lambda b, p, w: lower_bound_windowed_pallas(
             b, p, w, window_rows=TILE, tile=TILE, interpret=False),
