@@ -8,6 +8,10 @@ one int32 key column and its attributes:
   key, kind "primary"   the values 0..rows-1, shuffled (the paper's
                         section 5.1)
   key, kind "foreign"   uniform over the referenced table's rows
+                        ("references": <table>), or over 0..n-1
+                        ("domain": n), a domain no table holds: two
+                        tables that both draw from one domain share its
+                        values, and a join on them is m:n
   attribute             uniform integers in [low, high], times
                         "multiplier"; an 8-byte attribute is stored as two
                         int32 words, <name>_hi (the sign) and <name>_lo
@@ -57,8 +61,10 @@ def _tables(config: dict, rng, key_rng) -> dict:
         if key["kind"] == "primary":
             keys = jnp.arange(spec["rows"], dtype=jnp.int32)
         elif key["kind"] == "foreign":
+            domain = (key["domain"] if "domain" in key
+                      else tables[key["references"]]["rows"])
             keys = jax.random.randint(jax.random.fold_in(key_rng, t), (spec["rows"],),
-                                      0, tables[key["references"]]["rows"], jnp.int32)
+                                      0, domain, jnp.int32)
         else:
             raise ValueError(f"table {name}: key kind {key['kind']!r}")
         cols = {key["name"]: jax.random.permutation(jax.random.fold_in(trng, 0), keys)}

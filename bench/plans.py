@@ -4,8 +4,12 @@ A traffic file (`bench/traffic/<mix>.json`) gives its query as a list of
 steps, applied in order to the plan built so far:
 
   ["scan", "S"]                                           start from table S
-  ["join", {"table": "R", "key": "k"}]                    PK-FK join to R
+  ["join", {"table": "R", "key": "k"}]                    equi-join to R on k
   ["group_by", {"key": "k", "aggs": {"s1_lo": "sum"}}]    one row per key
+
+A join is an inner join on one key and names no kind: the planner
+decides from the keys' statistics (mode "auto") whether it is PK-FK (one
+side's key unique) or m:n.
 
 `build` turns the steps into the program's logical plan (the query a
 client submits); `bench/reference.py` evaluates the same steps in NumPy.

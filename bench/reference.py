@@ -2,11 +2,16 @@
 comparison that decides `correct`.
 
 `evaluate` runs the same steps as `plans.build` over host copies of the
-tables: a PK-FK join a direct-address lookup of each probe key among the
-unique build keys, a group-by exact integer sums per key. Keys are dense,
-as the generator makes them: none is negative or above 4 times the rows.
-Integer results wrap as int32 does, the precision the configurations
-state. It imports nothing of the program under test.
+tables. A join is an inner equi-join on one key: where the build keys are
+unique (PK-FK), a direct-address lookup of each probe key; otherwise
+(m:n) each probe row paired with every build row of its key, found by
+binary search in the build keys sorted once, and expanded in blocks of
+probe rows so that host memory stays near the answer's own size. A
+group-by is exact integer sums per key. Keys are dense, as the generator
+makes them: none is negative or above 4 times the rows (a join over
+other keys takes the binary search). Integer results wrap as int32 does,
+the precision the configurations state. It imports nothing of the
+program under test.
 
 `compare` checks one served answer against the reference's rows as a
 multiset, since no operator promises an order: each row is hashed over
@@ -35,16 +40,50 @@ def _dense(keys: np.ndarray) -> int:
 
 
 def _lookup(build: np.ndarray, probe: np.ndarray):
-    """(hit mask, build row of each hit) for unique build keys."""
-    hi = _dense(build)
+    """(hit mask, build row of each hit) for unique dense build keys; None
+    where a build key repeats or the keys are not dense."""
+    try:
+        hi = _dense(build)
+    except ValueError:
+        return None
     slot = np.full(hi + 1, -1, np.int64)
     slot[build] = np.arange(len(build))
     if np.count_nonzero(slot >= 0) != len(build):
-        raise ValueError("the reference join needs unique build keys")
+        return None
     inside = (probe >= 0) & (probe <= hi)
     row = np.full(len(probe), -1, np.int64)
     row[inside] = slot[probe[inside]]
     return row >= 0, row[row >= 0]
+
+
+PROBE_BLOCK = 1 << 20  # probe rows expanded at a time by `_expand`
+
+
+def _expand(cols: dict, right: dict, key: str) -> dict:
+    """The m:n inner join: every row of `cols` (the probe side) once for
+    each row of `right` (the build side) with an equal `key`, the build
+    side's other columns beside it."""
+    order = np.argsort(right[key], kind="stable")
+    keys = right[key][order]
+    lo = np.searchsorted(keys, cols[key], "left")
+    count = np.searchsorted(keys, cols[key], "right") - lo
+    end = np.cumsum(count)
+    total = int(end[-1]) if len(end) else 0
+    extra = [c for c in right if c not in cols]
+    out = {c: np.empty(total, v.dtype) for c, v in cols.items()}
+    out.update({c: np.empty(total, right[c].dtype) for c in extra})
+    for s in range(0, len(count), PROBE_BLOCK):
+        e = min(s + PROBE_BLOCK, len(count))
+        a, b = int(end[s] - count[s]), int(end[e - 1])
+        prow = np.repeat(np.arange(s, e), count[s:e])
+        # output row i of probe row p is its (i - first output row of p)-th
+        # match, build row order[lo[p] + that]
+        brow = order[lo[prow] + np.arange(a, b) - (end[prow] - count[prow])]
+        for c, v in cols.items():
+            out[c][a:b] = v[prow]
+        for c in extra:
+            out[c][a:b] = right[c][brow]
+    return out
 
 
 def _int32_sums(keys: np.ndarray, values: np.ndarray, groups: int) -> np.ndarray:
@@ -88,7 +127,11 @@ def evaluate(steps: list, tables: dict) -> dict:
     for op, arg in steps[1:]:
         if op == "join":
             right = tables[arg["table"]]
-            hit, row = _lookup(right[arg["key"]], cols[arg["key"]])
+            found = _lookup(right[arg["key"]], cols[arg["key"]])
+            if found is None:
+                cols = _expand(cols, right, arg["key"])
+                continue
+            hit, row = found
             if not hit.all():
                 cols = {c: v[hit] for c, v in cols.items()}
             cols.update({c: v[row] for c, v in right.items() if c not in cols})

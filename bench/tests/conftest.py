@@ -14,7 +14,7 @@ import pytest  # noqa: E402
 
 import run  # noqa: E402
 
-CELLS = ("tpch-q18-sf10.join-groupby", "tpch-q7-sf10.join")
+CELLS = ("tpch-q18-sf10.join-groupby", "tpch-q7-sf10.join", "tpch-q18-sf10.groupby")
 
 
 def tiny(name: str, divisor: int = 10_000) -> dict:

@@ -1,5 +1,7 @@
 """The on-device generator: deterministic per seed, and the keys and
 values each configuration asks for."""
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -59,3 +61,26 @@ def test_key_seed_draws_the_key_multisets(monkeypatch, name):
     assert not np.array_equal(fk_a, fk_b)
     assert fk_b.min() >= 0 and fk_b.max() < config["tables"]["R"]["rows"]
     np.testing.assert_array_equal(np.sort(b["R"]["k"]), np.sort(a["R"]["k"]))
+
+
+# sha256 over every column (tables and columns in sorted order, each name
+# then its bytes) of the cells' configurations cut by `tiny`, seed 2^31 + 7:
+# the chip readings that the bounds and limits rest on were taken on these
+# tables, so a change to the generator may not alter them
+TINY_TABLES_SHA256 = {
+    "tpch-q18-sf10.join-groupby":
+        "e59e4eed67dd4f42507881f707927e06cfb96174aa494b8e659b87efa03278cf",
+    "tpch-q7-sf10.join":
+        "7d5d7585feb66515c133dbcbef8bd6785f865ef261da463c0aaded99f760e628",
+}
+
+
+@pytest.mark.parametrize("name", TINY_TABLES_SHA256)
+def test_the_configurations_tables_are_unchanged(name):
+    tables = host(datagen.generate(tiny(name)["config"], 2**31 + 7))
+    h = hashlib.sha256()
+    for t in sorted(tables):
+        for c in sorted(tables[t]):
+            h.update(f"{t}.{c}".encode())
+            h.update(tables[t][c].tobytes())
+    assert h.hexdigest() == TINY_TABLES_SHA256[name]

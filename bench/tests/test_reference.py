@@ -11,7 +11,7 @@ import reference
 import run
 from conftest import tiny
 
-TRAFFIC = ("join-groupby", "join")
+TRAFFIC = ("join-groupby", "join", "groupby")
 CONFIGS = ("tpch-q18-sf10", "tpch-q7-sf10")
 
 

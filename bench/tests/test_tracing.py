@@ -69,10 +69,15 @@ def test_recorded_v5e_trace():
 
 def test_a_traced_run_reports_the_per_layer_metrics(cpu_run):
     from conftest import tiny
+    from test_spans import PROGRAM_METRICS
 
+    from repro.obs import metrics
+
+    metrics.reset()  # a run has a process, and so a registry, of its own
     out = cpu_run(tiny("tpch-q7-sf10.join"), trace=1)
     assert out["correct"]
-    # the CPU has no TPU plane: no op is found, so no device metric is read
-    assert set(out["metrics"]) == {"server_admit_ms", "plan_s"}
+    # the CPU has no TPU plane: no op is found, so no device metric is read;
+    # the program's spans are
+    assert set(out["metrics"]) == {"server_admit_ms", "plan_s"} | PROGRAM_METRICS
     assert out["device"]["window_s"] > 0 and out["device"]["busy_s"] == 0
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
